@@ -42,6 +42,7 @@ from repro.api import (BlessRSampler, BlessSampler, ChenYangSampler,
                        make_kernel)
 from repro.core import exact_rls, falkon_fit
 from repro.core.leverage import approx_rls_all
+from repro.runtime.compile_cache import enable_compile_cache
 
 _RECORDS: list[dict] = []
 _REPEATS = 1
@@ -456,6 +457,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny problem sizes (CI smoke job)")
     args = ap.parse_args()
+    enable_compile_cache()
     backend = None if args.backend == "auto" else args.backend
     _REPEATS = max(1, args.repeats)
     wanted = [w for w in (args.only or "").split(",") if w]

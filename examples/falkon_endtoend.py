@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from repro.api import BlessSampler, FalkonRegressor, FitConfig, make_kernel
 from repro.checkpoint import save_checkpoint
 from repro.core.distributed import data_mesh, falkon_fit_distributed
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def susy_like(n: int, d: int = 18, seed: int = 0):
@@ -46,6 +47,7 @@ def main() -> None:
                     help="kernel-operator backend (auto: BLESS by platform "
                          "heuristic / REPRO_BACKEND env, FALKON data-parallel)")
     args = ap.parse_args()
+    enable_compile_cache()
     backend = None if args.backend == "auto" else args.backend
 
     n_test = 8000

@@ -14,6 +14,9 @@ import jax.numpy as jnp
 from repro.api import (BlessSampler, ExactRlsSampler, FalkonRegressor,
                        FitConfig, KFoldSweep, kernel_family_names, make_kernel)
 from repro.core import approx_rls_all, exact_rls
+from repro.runtime.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # --- data: clustered inputs => low effective dimension (the regime
 # leverage scores are built for) -------------------------------------------

@@ -15,9 +15,11 @@ from repro.models.attention import bless_compress_cache
 from repro.optim import OptConfig
 from repro.serving.engine import ServeEngine
 from repro.training import make_train_step, train_state_init
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     cfg = smoke(get_config("qwen3-32b"))
     print(f"arch: {cfg.name} ({cfg.n_layers}L d={cfg.d_model})")
 

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.api import (BlessSampler, FalkonRegressor, FitConfig, KrrServer,
                        make_kernel)
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -26,6 +27,7 @@ def main() -> None:
     ap.add_argument("--backend", choices=["auto", "jnp", "pallas", "sharded", "stream"],
                     default="auto", help="kernel-operator backend override")
     args = ap.parse_args()
+    enable_compile_cache()
     backend = None if args.backend == "auto" else args.backend
 
     # --- fit once (clustered data: the low-d_eff regime BLESS exploits) ----

@@ -12,6 +12,7 @@ import argparse
 import sys
 
 from repro.launch.train import main as launch_main
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -21,6 +22,7 @@ def main() -> None:
     ap.add_argument("--no-smoke", action="store_true")
     ap.add_argument("--ckpt-dir", default="/tmp/lm_ckpt")
     args = ap.parse_args()
+    enable_compile_cache()
 
     argv = ["--arch", args.arch, "--steps", str(args.steps),
             "--ckpt-dir", args.ckpt_dir, "--ckpt-every", "25"]

@@ -37,8 +37,8 @@ from typing import Callable, ClassVar
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..kernels.falkon_matvec import ops as falkon_ops
 from ..kernels.gram import ops as gram_ops
@@ -77,13 +77,26 @@ PALLAS_MATVEC_BN = ((4096, 256), (None, 512))
 # the calibration recipe.
 _PALLAS_MIN_ROWS = 256  # interpret-mode never crosses over off-TPU; on-TPU floor
 _SHARD_MIN_ROWS = 1 << 15  # below this collective latency beats the split
-_STREAM_MIN_ROWS = 1 << 21  # above this X (+ its Gram tiles) stops fitting HBM
+_STREAM_MIN_ROWS = 1 << 21  # hosts whose device reports no memory size
 
 
 def _threshold(env: str, default: int) -> int:
     """An autotuned threshold with its env override (empty/unset -> default)."""
     raw = os.environ.get(env, "").strip()
     return int(raw) if raw else default
+
+
+def _stream_min_rows() -> int:
+    """Rows past which X stops fitting the device and is streamed instead.
+
+    Where the device reports its memory (TPU, GPU) X may take a quarter of
+    it at 512 B a row — fp32 with d padded to the 128 lanes the Pallas
+    tiles use — leaving the rest to the padded copies and (block, M) Gram
+    tiles; a 16 GB v5e keeps ~7.7M rows in core. Devices that report
+    nothing (the CPU backend) keep the fixed ``_STREAM_MIN_ROWS``.
+    """
+    limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+    return int(limit) // (4 * 512) if limit else _STREAM_MIN_ROWS
 
 
 def _pick(table, size: int):
@@ -665,7 +678,7 @@ def default_backend(n: int | None = None) -> Backend:
     else:
         picked = JnpBackend()
     if n is not None and n >= _threshold("REPRO_STREAM_MIN_ROWS",
-                                         _STREAM_MIN_ROWS):
+                                         _stream_min_rows()):
         from ..stream import StreamBackend
 
         return StreamBackend(inner=picked)

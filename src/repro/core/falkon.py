@@ -55,6 +55,7 @@ from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..testing import faults
 from . import health
@@ -62,6 +63,12 @@ from .gram import BackendLike, Kernel, resolve_backend
 from .leverage import CenterSet  # noqa: F401 — re-exported for callers
 
 Array = jax.Array
+
+#: Precision of the M-space algebra (the preconditioner, K_MM u). TPU runs
+#: a DEFAULT-precision fp32 matmul as one bf16 pass; with a (M, k) panel
+#: that would put ~1e-3 noise into every CG step. These O(M^2) products
+#: cost nothing next to the O(n M) K_nM sweep, so they run at full fp32.
+_M_SPACE = jax.lax.Precision.HIGHEST
 
 
 def _bcol(s: Array, v: Array) -> Array:
@@ -89,13 +96,37 @@ class Preconditioner(NamedTuple):
 
     def apply(self, v: Array) -> Array:
         """B v = (1/sqrt n) A^{-1/2} Q T^{-1} R^{-1} v,  v (q,) or (q, k)."""
-        u = self.q_iso @ (v / _bcol(self.t_diag * self.r_diag, v))
+        u = jnp.matmul(self.q_iso, v / _bcol(self.t_diag * self.r_diag, v),
+                       precision=_M_SPACE)
         return _bcol(self.inv_sqrt_a, u) * u / jnp.sqrt(self.n)
 
     def apply_t(self, v: Array) -> Array:
         """B^T v,  v (M,) or (M, k) -> (q,) or (q, k)."""
-        u = self.q_iso.T @ (_bcol(self.inv_sqrt_a, v) * v / jnp.sqrt(self.n))
+        u = jnp.matmul(self.q_iso.T, _bcol(self.inv_sqrt_a, v) * v / jnp.sqrt(self.n),
+                       precision=_M_SPACE)
         return u / _bcol(self.t_diag * self.r_diag, u)
+
+
+def _host_eigh(a: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    w, v = np.linalg.eigh(np.asarray(a))
+    return w.astype(a.dtype), v.astype(a.dtype)
+
+
+def _eigh(a: Array) -> tuple[Array, Array]:
+    """Ascending eigenpairs of the symmetric (M, M) ``a``.
+
+    XLA's TPU eigh is compiled for its size, and its compile time and host
+    memory grow steeply with M: compiled for v5e here it took 72 s and
+    4 GB at M = 1024, 233 s and 11 GB at M = 2048, and at M = 6052 it ran
+    a 40 GiB chip host out of memory. On a TPU the one factorization per
+    fit therefore runs in host LAPACK through a callback (an (M, M)
+    transfer each way); elsewhere XLA's own eigh.
+    """
+    if jax.default_backend() != "tpu":
+        return jnp.linalg.eigh(a)
+    shapes = (jax.ShapeDtypeStruct(a.shape[:1], a.dtype),
+              jax.ShapeDtypeStruct(a.shape, a.dtype))
+    return jax.pure_callback(_host_eigh, shapes, a)
 
 
 def make_preconditioner(kernel: Kernel, z: Array, a_diag: Array, lam: float, n: int,
@@ -109,7 +140,7 @@ def make_preconditioner(kernel: Kernel, z: Array, a_diag: Array, lam: float, n: 
     kmm = kernel.cross(z, z).astype(jnp.float32)
     inv_sqrt_a = (1.0 / jnp.sqrt(a_diag)).astype(jnp.float32)
     kt = kmm * (inv_sqrt_a[:, None] * inv_sqrt_a[None, :])
-    eig, vec = jnp.linalg.eigh(kt)
+    eig, vec = _eigh(kt)
     floor = jnp.maximum(eig[-1], 1e-30) * rank_tol
     keep = eig > floor
     # jit-friendly fixed shapes: keep all M columns but neutralize dropped
@@ -385,7 +416,7 @@ def _fused_falkon_solve(kernel: Kernel, xp: Array, yp: Array, centers: Array,
 
     def matvec(v: Array) -> Array:
         u = prec.apply(v)
-        w = quad(u) + lam * n_eff * (kmm @ u)
+        w = quad(u) + lam * n_eff * jnp.matmul(kmm, u, precision=_M_SPACE)
         return prec.apply_t(w)
 
     beta, resid = cg(matvec, prec.apply_t(kty), iters, trajectory=True)
@@ -585,7 +616,7 @@ def falkon_fit(
 
     def matvec(v: Array) -> Array:
         u = prec.apply(v)
-        w = quad(u) + lam * n_eff * (kmm @ u)
+        w = quad(u) + lam * n_eff * jnp.matmul(kmm, u, precision=_M_SPACE)
         return prec.apply_t(w)
 
     b = prec.apply_t(kty)

@@ -75,7 +75,7 @@ class Kernel:
         """Gram block ``k(x_i, z_j)`` of shape (n, m)."""
         fam = self.family
         if fam.dot_only:
-            return fam.epilogue(x @ z.T, fam.inv_scale(self.sigma))
+            return fam.epilogue(_dot_t(x, z), fam.inv_scale(self.sigma))
         return fam.epilogue(sq_dists(x, z), fam.inv_scale(self.sigma))
 
     def cross_unfused(self, x: jax.Array, z: jax.Array) -> jax.Array:
@@ -85,7 +85,7 @@ class Kernel:
         makes it unsafe inside deeply nested control flow (e.g. the CG
         while-loop), so hot *leaf* contractions opt in explicitly."""
         fam = self.family
-        pre = x @ z.T if fam.dot_only else sq_dists(x, z)
+        pre = _dot_t(x, z) if fam.dot_only else sq_dists(x, z)
         return _apply_epilogue(fam, pre, fam.inv_scale(self.sigma))
 
     def diag(self, x: jax.Array) -> jax.Array:
@@ -123,6 +123,14 @@ def _apply_epilogue(fam: KernelFamily, pre: jax.Array, c) -> jax.Array:
     return jax.lax.map(lambda b: fam.epilogue(b, c), blocks).reshape(pre.shape)
 
 
+def _dot_t(x: jax.Array, z: jax.Array) -> jax.Array:
+    """x z^T at full fp32. TPU runs a DEFAULT-precision fp32 matmul as one
+    bf16 pass; in the distance expansion below that error (~5e-4 relative
+    on a Gram block, measured on v5e) survives the cancellation of the
+    norms. The contraction is over d, small next to the (n, m) epilogue."""
+    return jnp.matmul(x, z.T, precision=jax.lax.Precision.HIGHEST)
+
+
 def sq_dists(x: jax.Array, z: jax.Array) -> jax.Array:
     """Pairwise squared Euclidean distances, MXU-friendly form.
 
@@ -131,7 +139,7 @@ def sq_dists(x: jax.Array, z: jax.Array) -> jax.Array:
     """
     xn = jnp.sum(x * x, axis=-1)[:, None]
     zn = jnp.sum(z * z, axis=-1)[None, :]
-    d2 = xn + zn - 2.0 * (x @ z.T)
+    d2 = xn + zn - 2.0 * _dot_t(x, z)
     return jnp.maximum(d2, 0.0)
 
 

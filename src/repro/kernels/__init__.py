@@ -2,8 +2,10 @@
 
 Each subpackage: <name>.py (pl.pallas_call + BlockSpec VMEM tiling),
 ops.py (jit'd public wrapper, pad/dispatch/interpret switch), ref.py
-(pure-jnp oracle). Validated in interpret mode on CPU; compiled natively
-on TPU (common.default_interpret()).
+(pure-jnp oracle). On a TPU the kernels compile through Mosaic; on the CPU
+backend the test suite runs them in interpret mode
+(common.default_interpret()), and tests/test_tpu_compile.py compiles the
+main-path ones for a described v5e chip.
 
   gram           k(X, Z) blocked Gram — every BLESS level's bulk work
   quadform       rowsum((G W) * G) — Eq. 3 leverage-score epilogue, fused

@@ -22,9 +22,10 @@ total, so the kernels are MXU-bound for M >= ~256 (DESIGN.md §2).
 
 Grid (n/bn,): Z (M, d) and the (M, kp) panel are VMEM-resident across the
 whole sweep (M*(d+kp) <= ~4M floats for the paper's d_eff-sized center
-sets). The reductions (``falkon_matvec``/``knm_t``) revisit one (M, kp)
-output block every step and accumulate; ``knm_matvec`` writes a private
-(bn, kp) block per step.
+sets); each call raises Mosaic's scoped-VMEM limit to what those blocks
+and the (bn, M) Gram tile need (``common.vmem_params``). The reductions
+(``falkon_matvec``/``knm_t``) revisit one (M, kp) output block every step
+and accumulate; ``knm_matvec`` writes a private (bn, kp) block per step.
 
 Mixed precision (``bf16=True``): the Gram tile's dominant (bn, d) x (d, M)
 product loads its operands as bf16 and accumulates on the MXU in fp32
@@ -41,6 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ...families import get_family
+from ..common import FP32, mxu_precision, vmem_params
 
 
 def _gram_tile(x: jax.Array, z: jax.Array, *, kind: str, inv_scale: float,
@@ -56,6 +58,7 @@ def _gram_tile(x: jax.Array, z: jax.Array, *, kind: str, inv_scale: float,
     fam = get_family(kind)
     xc, zc = (x.astype(jnp.bfloat16), z.astype(jnp.bfloat16)) if bf16 else (x, z)
     prod = jax.lax.dot_general(xc, zc, (((1,), (1,)), ((), ())),
+                               precision=mxu_precision(bf16),
                                preferred_element_type=jnp.float32)  # (bn, M)
     if fam.dot_only:
         return fam.epilogue(prod, inv_scale)
@@ -67,7 +70,7 @@ def _gram_tile(x: jax.Array, z: jax.Array, *, kind: str, inv_scale: float,
 def _panel_t_g(g: jax.Array, t: jax.Array) -> jax.Array:
     """G^T T: contract the shared (bn,) tile axis — (bn, M) x (bn, kp) ->
     (M, kp), fp32 MXU accumulation."""
-    return jax.lax.dot_general(g, t, (((0,), (0,)), ((), ())),
+    return jax.lax.dot_general(g, t, (((0,), (0,)), ((), ())), precision=FP32,
                                preferred_element_type=jnp.float32)
 
 
@@ -84,7 +87,7 @@ def _matvec_kernel(x_ref, z_ref, v_ref, o_ref, *, kind: str, inv_scale: float,
     g = _gram_tile(x, z, kind=kind, inv_scale=inv_scale, bf16=bf16)
     rows = i * bn + jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0)
     g = jnp.where(rows < n_valid, g, 0.0)  # padded X rows contribute nothing
-    t = g @ v_ref[...].astype(jnp.float32)  # (bn, kp): one G, every column
+    t = jnp.dot(g, v_ref[...].astype(jnp.float32), precision=FP32)  # (bn, kp): every column
     o_ref[...] += _panel_t_g(g, t)  # G^T T, still in VMEM
 
 
@@ -108,6 +111,7 @@ def falkon_matvec_pallas(x: jax.Array, z: jax.Array, v: jax.Array, inv_scale: fl
         ],
         out_specs=pl.BlockSpec((m, kp), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((m, kp), jnp.float32),
+        compiler_params=vmem_params([(bn, d), (m, d), (m, kp), (m, kp)], (bn, m)),
         interpret=interpret,
     )(x, z, v)
 
@@ -129,7 +133,7 @@ def _masked_matvec_kernel(x_ref, z_ref, v_ref, m_ref, o_ref, *, kind: str,
     g = _gram_tile(x, z, kind=kind, inv_scale=inv_scale, bf16=bf16)
     rows = i * bn + jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0)
     g = jnp.where(rows < n_valid, g, 0.0)
-    t = g @ v_ref[...].astype(jnp.float32)  # (bn, kp)
+    t = jnp.dot(g, v_ref[...].astype(jnp.float32), precision=FP32)  # (bn, kp)
     t = t * m_ref[...].astype(jnp.float32)  # per-column row exclusion
     o_ref[...] += _panel_t_g(g, t)
 
@@ -158,6 +162,8 @@ def falkon_matvec_masked_pallas(x: jax.Array, z: jax.Array, v: jax.Array,
         ],
         out_specs=pl.BlockSpec((m, kp), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((m, kp), jnp.float32),
+        compiler_params=vmem_params(
+            [(bn, d), (m, d), (m, kp), (bn, kp), (m, kp)], (bn, m)),
         interpret=interpret,
     )(x, z, v, mask)
 
@@ -199,6 +205,7 @@ def knm_t_pallas(x: jax.Array, z: jax.Array, y: jax.Array, inv_scale: float,
         ],
         out_specs=pl.BlockSpec((m, kp), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((m, kp), jnp.float32),
+        compiler_params=vmem_params([(bn, d), (m, d), (bn, kp), (m, kp)], (bn, m)),
         interpret=interpret,
     )(x, z, y)
 
@@ -214,7 +221,7 @@ def _knm_matvec_kernel(x_ref, z_ref, a_ref, o_ref, *, kind: str,
     x = x_ref[...].astype(jnp.float32)  # (bn, d)
     z = z_ref[...].astype(jnp.float32)  # (M, d)
     g = _gram_tile(x, z, kind=kind, inv_scale=inv_scale, bf16=bf16)
-    o_ref[...] = g @ a_ref[...].astype(jnp.float32)  # (bn, kp)
+    o_ref[...] = jnp.dot(g, a_ref[...].astype(jnp.float32), precision=FP32)  # (bn, kp)
 
 
 @partial(jax.jit, static_argnames=("kind", "bn", "interpret", "inv_scale", "bf16"))
@@ -235,5 +242,6 @@ def knm_matvec_pallas(x: jax.Array, z: jax.Array, alpha: jax.Array, inv_scale: f
         ],
         out_specs=pl.BlockSpec((bn, kp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, kp), jnp.float32),
+        compiler_params=vmem_params([(bn, d), (m, d), (m, kp), (bn, kp)], (bn, m)),
         interpret=interpret,
     )(x, z, alpha)

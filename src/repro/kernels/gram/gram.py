@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ...families import get_family
+from ..common import mxu_precision
 
 
 def _gram_kernel(x_ref, z_ref, o_ref, *, kind: str, inv_scale: float, bf16: bool):
@@ -29,6 +30,7 @@ def _gram_kernel(x_ref, z_ref, o_ref, *, kind: str, inv_scale: float, bf16: bool
     # stay fp32 (only the distance cross-term loses precision — DESIGN.md §2).
     xc, zc = (x.astype(jnp.bfloat16), z.astype(jnp.bfloat16)) if bf16 else (x, z)
     prod = jax.lax.dot_general(xc, zc, (((1,), (1,)), ((), ())),
+                               precision=mxu_precision(bf16),
                                preferred_element_type=jnp.float32)  # (bn, bm) on MXU
     if fam.dot_only:
         o_ref[...] = fam.epilogue(prod, inv_scale).astype(o_ref.dtype)

@@ -7,8 +7,9 @@ keeps it in VMEM, turning the op from memory- to compute-bound for M >= 512.
 
 Grid (i, k, j), j innermost: the (bn, bk) slab of G@W accumulates in VMEM
 scratch over j, then at j == last multiplies elementwise with G[i, k-tile]
-and row-reduces into the output block (indexed by i only — Pallas revisits
-it across k and j, which is legal under sequential TPU grids).
+and row-reduces into the lane-dense (1, bn) output block
+(``common.lane_row_sums``; indexed by i only — Pallas revisits it across
+k and j, which is legal under sequential TPU grids).
 """
 from __future__ import annotations
 
@@ -18,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..common import lane_row_sums, mxu_precision
 
 
 def _quadform_kernel(g_kj_ref, w_ref, g_ik_ref, o_ref, acc_ref, *, nj: int, nk: int,
@@ -39,12 +42,13 @@ def _quadform_kernel(g_kj_ref, w_ref, g_ik_ref, o_ref, acc_ref, *, nj: int, nk: 
     g = g_kj_ref[...].astype(dt)  # (bn, bj) — G[:, j-tile]
     w = w_ref[...].astype(dt)  # (bj, bk)
     acc_ref[...] += jax.lax.dot_general(g, w, (((1,), (0,)), ((), ())),
+                                        precision=mxu_precision(bf16),
                                         preferred_element_type=jnp.float32)
 
     @pl.when(j == nj - 1)
     def _epilogue():
         gk = g_ik_ref[...].astype(jnp.float32)  # (bn, bk) — G[:, k-tile]
-        o_ref[...] += jnp.sum(acc_ref[...] * gk, axis=1)
+        o_ref[...] += lane_row_sums(acc_ref[...] * gk)
 
 
 @partial(jax.jit, static_argnames=("bn", "bm", "interpret", "bf16"))
@@ -62,8 +66,8 @@ def quadform_pallas(g: jax.Array, w: jax.Array, *, bn: int = 256, bm: int = 256,
             pl.BlockSpec((bm, bm), lambda i, k, j: (j, k)),  # W[j, k]
             pl.BlockSpec((bn, bm), lambda i, k, j: (i, k)),  # G[:, k]
         ],
-        out_specs=pl.BlockSpec((bn,), lambda i, k, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
+        out_specs=pl.BlockSpec((1, bn), lambda i, k, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bn, bm), jnp.float32)],
         interpret=interpret,
-    )(g, w, g)
+    )(g, w, g)[0]

@@ -9,14 +9,15 @@ path moves the (R, M) Gram block through HBM three times (gram write,
 G @ W read, elementwise read); this kernel keeps it in VMEM for its whole
 lifetime: one MXU matmul forms the distance cross-term, the family epilogue
 (VPU) produces the Gram tile, a second MXU matmul contracts it against the
-resident (M, M) inverse W, and the score epilogue reduces to the (bn,)
-output — one dispatch per ladder level.
+resident (M, M) inverse W, and the score epilogue reduces each row to
+one lane of a (1, bn) output block — one dispatch per ladder level.
 
 Residency: z (M, d), W (M, M) and the center mask stay in VMEM across the
 whole grid (M ~ d_eff, the same bound that lets FALKON replicate its
 preconditioner), so the grid is 1-D over candidate tiles. ops.py guards the
 M <= 1024 VMEM budget (4 MB for W at fp32) and the backend composes the
-separate gram/quadform kernels above it.
+separate gram/quadform kernels above it. The mask, K_ii and the scores
+travel as lane-dense (1, ·) rows (``common.lane_row_sums``).
 
 The Cholesky-solve that produces W = (K_JJ + lam n A)^{-1} runs outside
 (LAPACK/XLA beats a hand-rolled Pallas factorization at M ~ d_eff); what
@@ -34,6 +35,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...families import get_family
+from ..common import lane_row_sums, mxu_precision, vmem_params
 
 
 def _rls_score_kernel(lamn_ref, x_ref, z_ref, w_ref, zmask_ref, kdiag_ref, o_ref,
@@ -43,6 +45,7 @@ def _rls_score_kernel(lamn_ref, x_ref, z_ref, w_ref, zmask_ref, kdiag_ref, o_ref
     z = z_ref[...].astype(jnp.float32)  # (M, d) — resident across the grid
     xc, zc = (x.astype(jnp.bfloat16), z.astype(jnp.bfloat16)) if bf16 else (x, z)
     prod = jax.lax.dot_general(xc, zc, (((1,), (1,)), ((), ())),
+                               precision=mxu_precision(bf16),
                                preferred_element_type=jnp.float32)  # (bn, M) MXU
     if fam.dot_only:
         pre = prod
@@ -52,12 +55,13 @@ def _rls_score_kernel(lamn_ref, x_ref, z_ref, w_ref, zmask_ref, kdiag_ref, o_ref
         pre = jnp.maximum(xn + zn - 2.0 * prod, 0.0)
     # family epilogue on the VPU; invalid center columns zeroed so the padded
     # rows of W (identity there) cannot leak k(x, 0)^2 into the quadform
-    g = fam.epilogue(pre, inv_scale) * zmask_ref[...][None, :]
+    g = fam.epilogue(pre, inv_scale) * zmask_ref[...]  # (1, M) row
     gw = g if not bf16 else g.astype(jnp.bfloat16)
     w = w_ref[...].astype(gw.dtype)  # (M, M) resident inverse
     acc = jax.lax.dot_general(gw, w, (((1,), (0,)), ((), ())),
+                              precision=mxu_precision(bf16),
                               preferred_element_type=jnp.float32)  # (bn, M) MXU
-    quad = jnp.sum(acc * g, axis=1)  # (bn,)
+    quad = lane_row_sums(acc * g)  # (1, bn)
     o_ref[...] = (kdiag_ref[...] - quad) / lamn_ref[0, 0]
 
 
@@ -84,10 +88,12 @@ def rls_score_pallas(x: jax.Array, z: jax.Array, w: jax.Array, zmask: jax.Array,
             pl.BlockSpec((bn, d), lambda i: (i, 0)),  # candidate tile
             pl.BlockSpec((m, d), lambda i: (0, 0)),  # z: resident
             pl.BlockSpec((m, m), lambda i: (0, 0)),  # W: resident
-            pl.BlockSpec((m,), lambda i: (0,)),  # center mask
-            pl.BlockSpec((bn,), lambda i: (i,)),  # K_ii tile
+            pl.BlockSpec((1, m), lambda i: (0, 0)),  # center mask
+            pl.BlockSpec((1, bn), lambda i: (0, i)),  # K_ii tile
         ],
-        out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
+        out_specs=pl.BlockSpec((1, bn), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
+        compiler_params=vmem_params(
+            [(bn, d), (m, d), (m, m), (1, m), (1, bn), (1, bn)], (bn, m)),
         interpret=interpret,
-    )(lamn, x, z, w, zmask, kdiag)
+    )(lamn, x, z, w, zmask.reshape(1, m), kdiag.reshape(1, n))[0]

@@ -25,18 +25,9 @@ TP = 16
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` across jax versions.
-
-    Older jax returns a per-device list of dicts, newer jax a single dict
-    (and either may return None for backends without a cost model). Returns
-    the entry-computation dict, or {} when unavailable.
-    """
-    cost = compiled.cost_analysis()
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost)
+    """``Compiled.cost_analysis()`` as a dict; {} for backends without a
+    cost model (which return None)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 @dataclasses.dataclass

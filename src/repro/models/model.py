@@ -35,23 +35,6 @@ Array = jax.Array
 TP = 16  # model-axis width of the production mesh
 
 
-@jax.custom_jvp
-def _opt_barrier(x: Array) -> Array:
-    """optimization_barrier with a differentiation rule.
-
-    The jax in this toolchain has no JVP for the raw primitive; the barrier
-    is a scheduling fence only, so the tangent passes through untouched
-    (matching the rule later jax versions ship).
-    """
-    return jax.lax.optimization_barrier(x)
-
-
-@_opt_barrier.defjvp
-def _opt_barrier_jvp(primals, tangents):
-    (x,), (t,) = primals, tangents
-    return _opt_barrier(x), t
-
-
 def padded_vocab(cfg: ArchConfig) -> int:
     return (cfg.vocab_size + 127) // 128 * 128
 
@@ -216,7 +199,7 @@ def _block_apply(p: dict, cfg: ArchConfig, j: int, x: Array, positions: Array,
         x = x + _attn_mixer(p["attn"], cfg, h, positions, mrope_pos)
     else:
         x = x + mamba_block(p["mamba"], cfg, h)
-    x = _opt_barrier(x)
+    x = jax.lax.optimization_barrier(x)
     kind = cfg.mlp_kind(j)
     if kind == "none":
         return x
@@ -227,7 +210,7 @@ def _block_apply(p: dict, cfg: ArchConfig, j: int, x: Array, positions: Array,
                           ep=cfg.moe_ep(TP))
     else:
         x = x + mlp_apply(p["mlp"], h, cfg.mlp_act)
-    return _opt_barrier(x)
+    return jax.lax.optimization_barrier(x)
 
 
 def _embed_in(params: dict, cfg: ArchConfig, batch: dict) -> Array:

@@ -53,18 +53,6 @@ def set_mesh_ctx(ctx: Optional[MeshCtx]) -> None:
     _TLS.ctx = ctx
 
 
-def activate_mesh(mesh: Mesh):
-    """Context manager making ``mesh`` the active physical mesh.
-
-    ``jax.set_mesh`` where it exists; on older jax the ``Mesh`` object itself
-    is the context manager. Both return a ctx usable as ``with
-    activate_mesh(m):``.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
 def get_mesh_ctx() -> Optional[MeshCtx]:
     return getattr(_TLS, "ctx", None)
 

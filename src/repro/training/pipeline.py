@@ -20,23 +20,14 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 Array = jax.Array
 
 
-def _axis_size(axis: str) -> int:
-    """jax.lax.axis_size where it exists; the axis-env lookup on older jax
-    (where ``axis_frame`` returns the size directly)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    size = jax.core.axis_frame(axis)
-    return size if isinstance(size, int) else size.size
-
-
 def _shift_right(x: Array, axis: str) -> Array:
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     return jax.lax.ppermute(x, axis, [(i, (i + 1) % n) for i in range(n)])
 
 
@@ -89,7 +80,7 @@ def pipeline_apply(stage_fn: Callable[[Any, Array], Array], n_stages: int,
     def run(params: Any, x: Array) -> Array:
         return shard_map(pipelined, mesh=mesh,
                          in_specs=(P(axis), P()), out_specs=P(),
-                         check_rep=False)(params, x)
+                         check_vma=False)(params, x)
 
     return run
 

@@ -50,6 +50,28 @@ def test_default_backend_heuristic_off_tpu():
     assert isinstance(big.inner, JnpBackend)
 
 
+def test_stream_threshold_follows_device_memory(monkeypatch):
+    # a device that reports its memory keeps X in core while it takes at
+    # most a quarter of it at 512 B a row: SUSY's 5M rows stay in core on a
+    # 16 GB chip, HIGGS-sized 11M rows stream
+    from repro.core import backend as backend_mod
+    from repro.stream import StreamBackend
+
+    class Device:
+        def __init__(self, stats):
+            self.stats = stats
+
+        def memory_stats(self):
+            return self.stats
+
+    monkeypatch.setattr(jax, "devices", lambda: [Device({"bytes_limit": 16 << 30})])
+    assert backend_mod._stream_min_rows() == (16 << 30) // 2048
+    assert isinstance(default_backend(5_000_000), JnpBackend)
+    assert isinstance(default_backend(11_000_000), StreamBackend)
+    monkeypatch.setattr(jax, "devices", lambda: [Device(None)])  # CPU: no stats
+    assert backend_mod._stream_min_rows() == backend_mod._STREAM_MIN_ROWS
+
+
 def test_repro_backend_env_override(monkeypatch):
     monkeypatch.setenv("REPRO_BACKEND", "pallas")
     assert isinstance(default_backend(), PallasBackend)

@@ -89,3 +89,22 @@ def test_falkon_with_pallas_backend_matches():
     ref = falkon_fit(KERN, x, y, z, lam, iters=25, backend="jnp")
     assert float(jnp.linalg.norm(fk.alpha - ref.alpha)
                  / jnp.linalg.norm(ref.alpha)) < 1e-3
+
+
+def test_preconditioner_host_eigh_matches_xla(monkeypatch):
+    # on a TPU the preconditioner's eigh runs in host LAPACK (XLA's TPU
+    # eigh does not compile at thousands of centers); same factors here
+    from repro.core import falkon as falkon_mod
+
+    x = jax.random.normal(jax.random.PRNGKey(3), (300, 5))
+    z, a = x[:96], jnp.linspace(0.5, 2.0, 96)
+    kern = make_kernel("gaussian", sigma=1.5)
+    want = falkon_mod.make_preconditioner(kern, z, a, 1e-3, 300)
+    monkeypatch.setattr(falkon_mod.jax, "default_backend", lambda: "tpu")
+    got = jax.jit(lambda zz: falkon_mod.make_preconditioner(kern, zz, a, 1e-3, 300))(z)
+    # same spectrum and the same reconstruction Q T^2 Q^T of A^-1/2 K_MM A^-1/2
+    # (B B^T itself inverts the near-null eigenvalues, so it is compared
+    # through what it is built from)
+    np.testing.assert_allclose(got.t_diag, want.t_diag, rtol=1e-4, atol=1e-6)
+    recon = lambda p: (p.q_iso * p.t_diag**2) @ p.q_iso.T  # noqa: E731
+    np.testing.assert_allclose(recon(got), recon(want), atol=1e-4)

@@ -15,7 +15,8 @@ _SCRIPT = textwrap.dedent("""
 
     n_stages, n_mb, mb, d = 4, 8, 2, 16
     n_layers = 8
-    mesh = jax.make_mesh((4,), ("pipe",))
+    from repro.launch.mesh import make_local_mesh
+    mesh = make_local_mesh(("pipe",))
     key = jax.random.PRNGKey(0)
     w = jax.random.normal(key, (n_layers, d, d)) * (0.5 / d**0.5)
     x = jax.random.normal(jax.random.PRNGKey(1), (n_mb, mb, d))

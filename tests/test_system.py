@@ -92,10 +92,11 @@ def test_train_step_sharded_runs_on_local_mesh():
     """The same pjit train step the dry-run lowers also *runs* on a real
     (1-device) mesh with full sharding machinery engaged."""
     from repro.launch.specs import input_specs
-    from repro.sharding.rules import MeshCtx, activate_mesh, set_mesh_ctx
+    from repro.launch.mesh import make_local_mesh
+    from repro.sharding.rules import MeshCtx, set_mesh_ctx
 
     cfg = dataclasses.replace(smoke(get_config("gemma-2b")), attn_chunk=64)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh(("data", "model"))
     ctx = MeshCtx(mesh=mesh)
     set_mesh_ctx(ctx)
     try:
@@ -104,7 +105,7 @@ def test_train_step_sharded_runs_on_local_mesh():
         state = train_state_init(cfg, jax.random.PRNGKey(0))
         pipe = SyntheticLM(cfg.vocab_size, batch=4, seq=64, seed=0)
         step = jax.jit(make_train_step(cfg, OptConfig(), loss_chunks=4))
-        with activate_mesh(mesh):
+        with jax.set_mesh(mesh):
             state, m = step(state, pipe.batch_at(0))
         assert jnp.isfinite(m["loss"])
     finally:

@@ -33,6 +33,7 @@ from ..core.falkon import FalkonModel, falkon_fit
 from ..core.gram import BackendLike, Kernel, make_kernel
 from ..core.leverage import CenterSet
 from ..core.nystrom import exact_krr, nystrom_krr
+from ..runtime import spans
 from ..stream import ChunkStore
 from .samplers import BlessSampler, Sampler
 
@@ -168,19 +169,21 @@ class FalkonRegressor(_KrrEstimator):
         reuse = (center_set is None and self.warm_start
                  and self.centers_ is not None
                  and self._fit_shape_ == x.shape)
-        if not reuse:
-            cs = center_set if center_set is not None else self.sampler.sample(
-                self._key(key), x, self.kernel, backend=cfg.backend)
-            m = int(cs.count)
-            self.center_set_ = cs
-            self.centers_ = x[cs.idx[:m]]
-            self.a_diag_ = cs.weight[:m]
-            self._fit_shape_ = x.shape
-        self.model_ = falkon_fit(self.kernel, x, y, self.centers_, cfg.lam,
-                                 a_diag=self.a_diag_, iters=cfg.iters,
-                                 backend=cfg.backend, callback=callback,
-                                 check_finite=cfg.check_finite,
-                                 row_mask=row_mask)
+        with spans.span("fit", n=x.shape[0], k=1 if y.ndim == 1 else y.shape[1]) as fit:
+            if not reuse:
+                cs = center_set if center_set is not None else self.sampler.sample(
+                    self._key(key), x, self.kernel, backend=cfg.backend)
+                m = int(cs.count)
+                self.center_set_ = cs
+                self.centers_ = x[cs.idx[:m]]
+                self.a_diag_ = cs.weight[:m]
+                self._fit_shape_ = x.shape
+            fit.set_metadata(m=self.centers_.shape[0])
+            self.model_ = falkon_fit(self.kernel, x, y, self.centers_, cfg.lam,
+                                     a_diag=self.a_diag_, iters=cfg.iters,
+                                     backend=cfg.backend, callback=callback,
+                                     check_finite=cfg.check_finite,
+                                     row_mask=row_mask)
         return self
 
 

@@ -19,9 +19,11 @@ O(1) per array.
 Buffers use *quarter-pow2* buckets (``_bucket``): pow2 up to 32, then the
 smallest of {5/8, 3/4, 7/8, 1} * pow2 that fits. Padding waste drops from
 <= 2x to <= 1.25x while the jit cache stays O(log) sized — a draw of 1045
-candidates runs on a 1280 buffer, not 2048. ``_LADDER_TRACES`` counts
-ladder retraces (the analogue of ``falkon._FUSED_FIT_TRACES``): repeating a
-ladder at the same (n, kernel, lam, q*) hits the cache end to end.
+candidates runs on a 1280 buffer, not 2048. Each phase counts its traces
+as ``bless.<phase>`` in ``runtime.spans`` (``retraces("bless")`` sums them):
+repeating a ladder at the same (n, kernel, lam, q*) hits the cache end to
+end. Each level on the host is the span ``repro.bless.level``, and its
+blocking fetch of the next level's buffer size ``repro.bless.sync``.
 
 Two exact-optimization notes (distributionally identical to the paper's
 pseudocode, DESIGN.md §8):
@@ -52,16 +54,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..runtime import spans
 from .gram import BackendLike, Kernel, resolve_backend
 from .leverage import _SCORE_FLOOR, CenterSet
 from .sampling import categorical
 
 Array = jax.Array
-
-#: Retrace counter for the jitted ladder phases (incremented at trace time,
-#: mirroring ``falkon._FUSED_FIT_TRACES``). Host-driven backends bump it per
-#: call; the zero-retrace guard in tests pins the jnp path.
-_LADDER_TRACES = 0
 
 #: Kept name: the Alg. 1 line-9 draw is *with replacement*, i.e. the jitted
 #: inverse-CDF categorical (see ``repro.core.sampling`` for why it is not a
@@ -217,8 +215,6 @@ def _bless_score_impl(k_u, x, kernel, centers, lam_h, r_h, *,
     Returns (cand_idx, s, wvec, tot, d_h) with wvec = c * s the unnormalized
     sampling weights of Alg. 1 line 8.
     """
-    global _LADDER_TRACES
-    _LADDER_TRACES += 1
     n = x.shape[0]
     lamn = lam_h * n
     draws = jax.random.randint(k_u, (rbuf,), 0, n)
@@ -241,17 +237,18 @@ def _bless_score_impl(k_u, x, kernel, centers, lam_h, r_h, *,
     return cand_idx, s, wvec, tot, d_h
 
 
-_bless_score = partial(jax.jit, static_argnames=("backend", "rbuf", "dbuf",
-                                                 "counts"))(_bless_score_impl)
+# The retrace mark goes on what jit traces: host-driven backends call the
+# impl eagerly, and an eager call is no retrace.
+_bless_score = partial(jax.jit, static_argnames=("backend", "rbuf", "dbuf", "counts"))(
+    spans.retrace("bless.score")(_bless_score_impl))
 
 
 @partial(jax.jit, static_argnames=("mbuf", "n"))
+@spans.retrace("bless.sample")
 def _bless_sample(k_j, cand_idx, s, wvec, tot, r_h, m_h, *, mbuf, n):
     """Level sample phase (Alg. 1 lines 9-10): M_h categorical draws from
     wvec (with replacement), the A_h weights, and the distinct-center count
     the host needs to size the next level's dedup buffer."""
-    global _LADDER_TRACES
-    _LADDER_TRACES += 1
     pos = categorical(k_j, wvec, mbuf)
     j_mask = jnp.arange(mbuf) < m_h
     scale = r_h.astype(jnp.float32) * m_h.astype(jnp.float32) / n
@@ -314,27 +311,30 @@ def bless(
     centers = CenterSet.empty(1)
     dbuf = 1
     levels: list[BlessLevel] = []
-    for lam_h in lams:
+    for h, lam_h in enumerate(lams):
         key, k_u, k_j = jax.random.split(key, 3)
         # -- line 4/5: uniform candidates U_h, R_h = q1 * min(kappa^2/lam_h, n)
         r_h = max(8, int(math.ceil(q1 * min(kap2 / lam_h, n))))
         rbuf = _bucket(r_h)
         counts = n <= rbuf  # score each point once, carry multiplicities
-        cand_idx, s, wvec, tot, d_dev = score_fn(
-            k_u, x, kernel, centers, jnp.asarray(lam_h, jnp.float32),
-            jnp.asarray(r_h, jnp.int32),
-            backend=backend, rbuf=rbuf, dbuf=dbuf, counts=counts)
-        # -- line 7/8: d_h (the only level-boundary sync) -> M_h
-        d_h = float(d_dev)
-        m_h = max(8, int(math.ceil(q2 * d_h)))
-        if m_cap is not None:
-            m_h = min(m_h, m_cap)
-        mbuf = _bucket(m_h)
-        # -- line 9/10: J_h ~ Multinomial(P_h, U_h), A_h weights
-        centers, n_distinct = _bless_sample(
-            k_j, cand_idx, s, wvec, tot, jnp.asarray(r_h, jnp.int32),
-            jnp.asarray(m_h, jnp.int32), mbuf=mbuf, n=n)
-        dbuf = _bucket(int(n_distinct))
+        with spans.span("bless.level", h=h, r_h=r_h) as level:
+            cand_idx, s, wvec, tot, d_dev = score_fn(
+                k_u, x, kernel, centers, jnp.asarray(lam_h, jnp.float32),
+                jnp.asarray(r_h, jnp.int32),
+                backend=backend, rbuf=rbuf, dbuf=dbuf, counts=counts)
+            # -- line 7/8: d_h (a blocking fetch, waits for the scores) -> M_h
+            d_h = float(d_dev)
+            m_h = max(8, int(math.ceil(q2 * d_h)))
+            if m_cap is not None:
+                m_h = min(m_h, m_cap)
+            level.set_metadata(m_h=m_h)
+            mbuf = _bucket(m_h)
+            # -- line 9/10: J_h ~ Multinomial(P_h, U_h), A_h weights
+            centers, n_distinct = _bless_sample(
+                k_j, cand_idx, s, wvec, tot, jnp.asarray(r_h, jnp.int32),
+                jnp.asarray(m_h, jnp.int32), mbuf=mbuf, n=n)
+            with spans.span("bless.sync", h=h):
+                dbuf = _bucket(int(n_distinct))
         levels.append(BlessLevel(lam=lam_h, centers=centers, d_h=d_h, m_h=m_h, r_h=r_h))
     return BlessResult(levels=levels, lam_path=lams)
 
@@ -344,12 +344,11 @@ def bless(
 # =============================================================================
 
 
+@spans.retrace("bless.r_gates")
 def _blessr_gates_impl(k_u, betas, n):
     """All H Bernoulli pre-filters (Alg. 2 lines 5-8) in one dispatch:
     per level the survivor-first index order and the survivor count — one
     host fetch of (H,) sizes instead of H gate/argsort round-trips."""
-    global _LADDER_TRACES
-    _LADDER_TRACES += 1
     h = betas.shape[0]
     gate = jax.random.uniform(k_u, (h, n)) < betas[:, None]
     r_vec = jnp.sum(gate, axis=1).astype(jnp.int32)
@@ -391,11 +390,10 @@ def _compact_body(u_idx, p, acc, m_h, *, mbuf, m_cap):
 
 
 @partial(jax.jit, static_argnames=("mbuf", "m_cap"))
+@spans.retrace("bless.r_compact")
 def _blessr_compact(u_idx, p, acc, m_h, *, mbuf, m_cap):
     """Standalone compaction — only the ladder's final level needs it (every
     other level's compaction is fused into the next level's dispatch)."""
-    global _LADDER_TRACES
-    _LADDER_TRACES += 1
     return _compact_body(u_idx, p, acc, m_h, mbuf=mbuf, m_cap=m_cap)
 
 
@@ -412,8 +410,6 @@ def _blessr_level_impl(k_a, x, kernel, order_h, pu, pp, pacc, pm, lam_prev,
     the Bernoulli pre-filter): the survivor order is the identity, so the
     candidate gather is skipped entirely and rbuf == n.
     """
-    global _LADDER_TRACES
-    _LADDER_TRACES += 1
     n = x.shape[0]
     centers = _compact_body(pu, pp, pacc, pm, mbuf=dbuf, m_cap=m_cap)
     if identity_order:
@@ -443,7 +439,8 @@ def _blessr_level_impl(k_a, x, kernel, order_h, pu, pp, pacc, pm, lam_prev,
 
 
 _blessr_level = partial(jax.jit, static_argnames=(
-    "backend", "rbuf", "dbuf", "m_cap", "identity_order"))(_blessr_level_impl)
+    "backend", "rbuf", "dbuf", "m_cap", "identity_order"))(
+    spans.retrace("bless.r_level")(_blessr_level_impl))
 
 
 def bless_r(
@@ -508,16 +505,19 @@ def bless_r(
         identity = betas_host[h] >= 1.0
         rbuf = n if identity else min(_bucket(r_h), n)
         order_h = no_order if identity else orders[row_of[h]]
-        # -- lines 9-12: J_{h-1} pack + scores at lam_{h-1} + acceptances
-        packed, u_idx, p, acc, stats = level_fn(
-            keys[h], x, kernel, order_h, *prev, lam_prev, betas_host[h],
-            q2, r_h, backend=backend, rbuf=rbuf, dbuf=dbuf, m_cap=m_cap,
-            identity_order=identity)
-        if pending is not None:
-            levels.append(BlessLevel(centers=packed, **pending))
-            pending = None
-        stats = np.asarray(stats)  # the level's one blocking sync
-        m_h = int(stats[0])
+        with spans.span("bless.level", h=h, r_h=r_h) as level:
+            # -- lines 9-12: J_{h-1} pack + scores at lam_{h-1} + acceptances
+            packed, u_idx, p, acc, stats = level_fn(
+                keys[h], x, kernel, order_h, *prev, lam_prev, betas_host[h],
+                q2, r_h, backend=backend, rbuf=rbuf, dbuf=dbuf, m_cap=m_cap,
+                identity_order=identity)
+            if pending is not None:
+                levels.append(BlessLevel(centers=packed, **pending))
+                pending = None
+            with spans.span("bless.sync", h=h):
+                stats = np.asarray(stats)  # the level's one blocking sync
+            m_h = int(stats[0])
+            level.set_metadata(m_h=m_h)
         d_h = float(n / r_h * stats[1])
         lam_prev = lam_h
         if m_h == 0:

@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..runtime import spans
 from ..testing import faults
 from . import health
 from .gram import BackendLike, Kernel, resolve_backend
@@ -108,8 +109,9 @@ class Preconditioner(NamedTuple):
 
 
 def _host_eigh(a: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-    w, v = np.linalg.eigh(np.asarray(a))
-    return w.astype(a.dtype), v.astype(a.dtype)
+    with spans.span("precond.eigh", m=a.shape[0]):
+        w, v = np.linalg.eigh(np.asarray(a))
+        return w.astype(a.dtype), v.astype(a.dtype)
 
 
 def _eigh(a: Array) -> tuple[Array, Array]:
@@ -263,7 +265,10 @@ def cg(matvec: Callable[[Array], Array], b: Array, iters: int,
     recorded residual simply plateaus — the recorded value stays exact.)
 
     With ``callback`` the loop runs on host (per-iteration metrics for the
-    Fig. 4/5 analogues); otherwise it is a single jitted lax.fori_loop.
+    Fig. 4/5 analogues); otherwise it is a single jitted lax.fori_loop,
+    traced anew on every call (``falkon.cg`` in ``runtime.spans``): called
+    outside a jit, as the host-driven fit calls it, it is then compiled or
+    loaded from the persistent cache again.
     """
     rs0 = jnp.sum(b * b, axis=0)
 
@@ -298,21 +303,16 @@ def cg(matvec: Callable[[Array], Array], b: Array, iters: int,
             inner = step(inner)
             return inner, traj.at[i + 1].set(inner[3])
 
-        (beta, *_), traj = jax.lax.fori_loop(0, iters, tstep, (state, traj0))
+        with spans.retrace("falkon.cg"):
+            (beta, *_), traj = jax.lax.fori_loop(0, iters, tstep, (state, traj0))
         return beta, traj
-    return jax.lax.fori_loop(0, iters, lambda _, s: step(s), state)[0]
+    with spans.retrace("falkon.cg"):
+        return jax.lax.fori_loop(0, iters, lambda _, s: step(s), state)[0]
 
 
 # ---------------------------------------------------------------------------
 # Fused whole-fit path (see module docstring / DESIGN.md §2.4)
 # ---------------------------------------------------------------------------
-
-#: times _fused_falkon_solve was traced (i.e. compiled for a new shape
-#: bucket). Tests assert a second same-bucket fit does NOT bump this — the
-#: whole solve is then a single cached compiled call with zero host-side CG
-#: dispatches.
-_FUSED_FIT_TRACES = 0
-
 
 def _fit_block(backend) -> int:
     """Stream-block (and row-bucket granularity) for a jit-safe backend."""
@@ -383,11 +383,15 @@ def _masked_knm_ops(kernel: Kernel, xp: Array, z: Array, yp: Array,
 
 @partial(jax.jit, static_argnames=("iters", "backend", "block"),
          donate_argnames=("yp",))
+@spans.retrace("falkon.fused_fit")
 def _fused_falkon_solve(kernel: Kernel, xp: Array, yp: Array, centers: Array,
                         a_diag: Array, lam: Array, n: Array, *, iters: int,
                         backend, block: int,
                         col_mask: Array | None = None) -> tuple[Array, Array]:
     """Preconditioner + multi-RHS CG + alpha recovery as one compiled program.
+
+    Each trace counts as ``falkon.fused_fit`` (``runtime.spans``): a second
+    fit in the same shape bucket adds none, and is then one cached call.
 
     ``yp`` is the bucket-padded target: (n_pad,) for single-output, or an
     (n_pad, kb) panel for multi-RHS; alpha comes back with matching shape
@@ -403,8 +407,6 @@ def _fused_falkon_solve(kernel: Kernel, xp: Array, yp: Array, centers: Array,
     under the (c^2 A, c b) rescaling that a per-column 1/sqrt(n_j) would
     introduce, so the shared factorization changes nothing (DESIGN.md §2.4).
     """
-    global _FUSED_FIT_TRACES
-    _FUSED_FIT_TRACES += 1
     row_mask = jnp.arange(xp.shape[0]) < n
     prec = make_preconditioner(kernel, centers, a_diag, lam, n)
     kmm = backend.gram_block(kernel, centers, centers)

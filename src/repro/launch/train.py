@@ -22,7 +22,7 @@ from repro.configs import get_config, list_archs, smoke
 from repro.data import SyntheticLM
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.optim import OptConfig
-from repro.runtime import FaultTolerantLoop, HeartbeatMonitor
+from repro.runtime.monitor import FaultTolerantLoop, HeartbeatMonitor
 from repro.sharding.rules import MeshCtx, set_mesh_ctx
 from repro.training import make_train_step, train_state_init
 

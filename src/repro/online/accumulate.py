@@ -43,15 +43,10 @@ import jax.numpy as jnp
 
 from ..core.falkon import cg, make_preconditioner
 from ..core.gram import Kernel
+from ..runtime import spans
 from ..stream.store import _TRACKER, device_chunks
 
 Array = jax.Array
-
-#: Times the fused accumulator solve was traced (a new (M, k, iters)
-#: bucket). Warm-refit tests assert repeated same-shape refits do NOT bump
-#: this — each refit is then one cached compiled call.
-_ACC_SOLVE_TRACES = 0
-
 
 def _absorb_chunk(kernel: Kernel, xb: Array, z: Array, yb: Array,
                   h: Array, b: Array, *, inner) -> tuple[Array, Array]:
@@ -81,14 +76,13 @@ def absorb(kernel: Kernel, x, y, z: Array, h: Array, b: Array, *, inner,
 
 
 @partial(jax.jit, static_argnames=("iters",))
+@spans.retrace("online.acc_solve")
 def _acc_solve(kernel: Kernel, h: Array, b: Array, centers: Array,
                a_diag: Array, lam: Array, n: Array, *,
                iters: int) -> tuple[Array, Array]:
     """Preconditioned CG on the accumulated normal equations, one compiled
     program: (H + lam n K_MM) alpha = b with B from Def. 2. Everything is
     (M, M)-sized — no data pass. Returns (alpha, residual trajectory)."""
-    global _ACC_SOLVE_TRACES
-    _ACC_SOLVE_TRACES += 1
     prec = make_preconditioner(kernel, centers, a_diag, lam, n)
     kmm = kernel.cross(centers, centers).astype(jnp.float32)
 
@@ -107,7 +101,7 @@ def solve_accumulators(kernel: Kernel, h: Array, b: Array, centers: Array,
 
     ``lam`` and ``n`` are traced (sweeping them never recompiles); ``iters``
     and the array shapes key the jit cache — repeated warm refits reuse one
-    executable (see ``_ACC_SOLVE_TRACES``).
+    executable (``online.acc_solve`` in ``runtime.spans`` counts the traces).
     """
     m = centers.shape[0]
     a_diag = (jnp.ones((m,), jnp.float32) if a_diag is None

@@ -1,5 +1,3 @@
-from .compress import compressed_psum_bf16, int8_compress, int8_decompress
-from .monitor import FaultTolerantLoop, HeartbeatMonitor
-
-__all__ = ["compressed_psum_bf16", "int8_compress", "int8_decompress",
-           "FaultTolerantLoop", "HeartbeatMonitor"]
+"""Runtime pieces, imported by module (``repro.runtime.spans``,
+``repro.runtime.monitor``, ...) so that the KRR solver's spans load nothing
+of the LM training loop."""

@@ -14,8 +14,8 @@ from repro.api import (BlessRSampler, BlessSampler, ExactKrr, ExactRlsSampler,
                        RecursiveRlsSampler, Sampler, SqueakSampler,
                        TwoPassSampler, UniformSampler, make_kernel)
 from repro.core import falkon_bless_fit, falkon_fit, nystrom_krr
-from repro.core import falkon as falkon_mod
 from repro.core.leverage import CenterSet
+from repro.runtime import spans
 
 KERN = make_kernel("gaussian", sigma=1.5)
 BACKENDS = ["jnp", "pallas", "sharded"]
@@ -211,12 +211,12 @@ def test_warm_start_refit_rides_fused_cache():
                           warm_start=True)
     est.fit(x, y)
     centers0 = est.centers_
-    traces0 = falkon_mod._FUSED_FIT_TRACES
+    traces0 = spans.retraces("falkon.fused_fit")
     # refit with new targets and a new lam: centers reused, zero retraces
     est.config = FitConfig(lam=1e-4, iters=17, backend="jnp")
     est.fit(x, jnp.cos(x[:, 0]))
     assert est.centers_ is centers0  # no re-sampling
-    assert falkon_mod._FUSED_FIT_TRACES == traces0  # fused-fit cache hit
+    assert spans.retraces("falkon.fused_fit") == traces0  # fused-fit cache hit
     # without warm_start the sampler runs again (same draw, new arrays)
     est.warm_start = False
     est.fit(x, y)

@@ -11,7 +11,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.checkpoint import (AsyncCheckpointer, latest_step,
                               restore_checkpoint, save_checkpoint)
-from repro.runtime import FaultTolerantLoop, HeartbeatMonitor
+from repro.runtime.monitor import FaultTolerantLoop, HeartbeatMonitor
 from repro.runtime.compress import int8_compress, int8_decompress
 
 

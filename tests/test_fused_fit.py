@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import PallasBackend, falkon_fit, make_kernel, nystrom_krr
-from repro.core import falkon as falkon_mod
+from repro.runtime import spans
 
 KERN = make_kernel("gaussian", sigma=1.5)
 
@@ -26,9 +26,9 @@ def test_fused_fit_compiles_once_per_bucket():
     files (the jit cache is process-wide) cannot mask the first trace.
     """
     x, y, z = _problem(m=48)
-    t0 = falkon_mod._FUSED_FIT_TRACES
+    t0 = spans.retraces("falkon.fused_fit")
     m1 = falkon_fit(KERN, x, y, z, 1e-3, iters=19, backend="jnp")
-    traces_after_first = falkon_mod._FUSED_FIT_TRACES
+    traces_after_first = spans.retraces("falkon.fused_fit")
     assert traces_after_first == t0 + 1  # first call compiled the bucket
     # same shapes -> cache hit
     falkon_fit(KERN, x, y, z, 1e-3, iters=19, backend="jnp")
@@ -38,11 +38,11 @@ def test_fused_fit_compiles_once_per_bucket():
     falkon_fit(KERN, x, y, z, 1e-4, iters=19, backend="jnp")
     falkon_fit(make_kernel("gaussian", sigma=2.5), x, y, z, 1e-3, iters=19,
                backend="jnp")
-    assert falkon_mod._FUSED_FIT_TRACES == traces_after_first
+    assert spans.retraces("falkon.fused_fit") == traces_after_first
     # different iters is a static key -> recompiles (sanity that the counter
     # actually observes tracing)
     falkon_fit(KERN, x, y, z, 1e-3, iters=18, backend="jnp")
-    assert falkon_mod._FUSED_FIT_TRACES == traces_after_first + 1
+    assert spans.retraces("falkon.fused_fit") == traces_after_first + 1
     assert m1.alpha.shape == (z.shape[0],)
 
 

@@ -11,8 +11,8 @@ import pytest
 
 from repro.api import FitConfig, KFoldSweep, UniformSampler
 from repro.core import cg, falkon_fit, make_kernel
-from repro.core import falkon as falkon_mod
 from repro.core.gram import resolve_backend
+from repro.runtime import spans
 
 BACKENDS = ["jnp", "pallas", "sharded"]
 MASK_BACKENDS = ["jnp", "pallas", "sharded", "stream"]
@@ -80,17 +80,17 @@ def test_fused_cache_k_bucket_zero_retrace():
     files' fits cannot mask the traces)."""
     kern = make_kernel("gaussian", sigma=1.5)
     x, y8, z = _problem(m=44, k=8)
-    t0 = falkon_mod._FUSED_FIT_TRACES
+    t0 = spans.retraces("falkon.fused_fit")
     falkon_fit(kern, x, y8[:, :3], z, 1e-3, iters=13, backend="jnp")
-    assert falkon_mod._FUSED_FIT_TRACES == t0 + 1  # k=3 compiled bucket kb=4
+    assert spans.retraces("falkon.fused_fit") == t0 + 1  # k=3 compiled bucket kb=4
     falkon_fit(kern, x, y8[:, :4], z, 1e-3, iters=13, backend="jnp")
-    assert falkon_mod._FUSED_FIT_TRACES == t0 + 1  # k=4: same bucket, no trace
+    assert spans.retraces("falkon.fused_fit") == t0 + 1  # k=4: same bucket, no trace
     falkon_fit(kern, x, y8[:, :5], z, 1e-3, iters=13, backend="jnp")
-    assert falkon_mod._FUSED_FIT_TRACES == t0 + 2  # k=5 -> bucket kb=8
+    assert spans.retraces("falkon.fused_fit") == t0 + 2  # k=5 -> bucket kb=8
     falkon_fit(kern, x, y8, z, 1e-3, iters=13, backend="jnp")
-    assert falkon_mod._FUSED_FIT_TRACES == t0 + 2  # k=8 rides the kb=8 bucket
+    assert spans.retraces("falkon.fused_fit") == t0 + 2  # k=8 rides the kb=8 bucket
     falkon_fit(kern, x, y8[:, 0], z, 1e-3, iters=13, backend="jnp")
-    assert falkon_mod._FUSED_FIT_TRACES == t0 + 3  # single-output: kb=1
+    assert spans.retraces("falkon.fused_fit") == t0 + 3  # single-output: kb=1
 
 
 def test_k_bucket_padding_columns_are_inert():
@@ -306,9 +306,9 @@ def test_kfold_sweep_rides_fused_cache():
     sweep = KFoldSweep(kernel="gaussian", sigma=1.5, sampler=UniformSampler(m=52),
                        lams=LAMS, folds=4, iters=12, backend="jnp", seed=3)
     res1 = sweep.run(x, y)
-    t0 = falkon_mod._FUSED_FIT_TRACES
+    t0 = spans.retraces("falkon.fused_fit")
     res2 = sweep.run(x, y)  # same shapes end to end -> zero retraces
-    assert falkon_mod._FUSED_FIT_TRACES == t0
+    assert spans.retraces("falkon.fused_fit") == t0
     np.testing.assert_allclose(res1.scores, res2.scores, rtol=1e-6, atol=1e-7)
 
 

@@ -12,7 +12,7 @@ from repro.api import (OnlineFalkon, ResumeMismatchError, UniformSampler,
                        as_prng_key, resumable_streamed_fit)
 from repro.checkpoint import checkpoint_extra, latest_step, restore_checkpoint
 from repro.core import falkon_fit, health, make_kernel
-from repro.online import accumulate
+from repro.runtime import spans
 from repro.stream import ChunkStore
 
 KERN = make_kernel("gaussian", sigma=1.5)
@@ -78,11 +78,11 @@ def test_warm_refit_rides_one_executable(data):
     of = OnlineFalkon(KERN, x[:M], LAM, x=x[:1000], y=y[:1000], iters=ITERS,
                       chunk=512)
     of.refit()
-    before = accumulate._ACC_SOLVE_TRACES
+    before = spans.retraces("online.acc_solve")
     for i in range(1000, 1800, 200):
         of.append(x[i:i + 200], y[i:i + 200])
         of.refit()
-    assert accumulate._ACC_SOLVE_TRACES == before
+    assert spans.retraces("online.acc_solve") == before
     assert of.counters["refits"] == 5
 
 

@@ -21,10 +21,11 @@ from repro.core import resolve_backend
 from repro.core.chen_yang import fast_spectral_rls
 
 # the package re-exports the *function* bless under the submodule's name;
-# the retrace guard needs the module itself for its _LADDER_TRACES counter
+# the retrace guard runs the module's own ladders
 bless_mod = importlib.import_module("repro.core.bless")
 from repro.core.sampling import gumbel_topk
 from repro.kernels.rls_score import rls_score_ref
+from repro.runtime import spans
 
 FAMILIES = ["gaussian", "laplacian", "linear", "matern32", "cauchy"]
 BACKENDS = ["jnp", "pallas", "sharded"]
@@ -102,9 +103,9 @@ def test_ladder_zero_retrace_on_repeat(alg):
     kernel = make_kernel("gaussian", sigma=1.5)
     run = getattr(bless_mod, alg)
     run(jax.random.PRNGKey(0), x, kernel, 1e-2, backend="jnp")
-    before = bless_mod._LADDER_TRACES
+    before = spans.retraces("bless")
     out = run(jax.random.PRNGKey(0), x, kernel, 1e-2, backend="jnp")
-    assert bless_mod._LADDER_TRACES == before
+    assert spans.retraces("bless") == before
     assert int(out.final.centers.count) > 0
 
 

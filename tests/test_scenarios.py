@@ -230,7 +230,7 @@ def test_classifier_validates_inputs():
 def test_classifier_warm_start_rides_fused_cache():
     """Warm-start refits keep the centers and the k-bucketed executable:
     zero retraces on the second fit."""
-    from repro.core import falkon as falkon_mod
+    from repro.runtime import spans
 
     x, labels = _class_problem(n=280)
     clf = FalkonClassifier(kernel="gaussian", sigma=2.0,
@@ -238,10 +238,10 @@ def test_classifier_warm_start_rides_fused_cache():
                            config=FitConfig(lam=1e-4, iters=11, backend="jnp"))
     clf.fit(x, labels)
     centers = clf.centers_
-    t0 = falkon_mod._FUSED_FIT_TRACES
+    t0 = spans.retraces("falkon.fused_fit")
     clf.config = FitConfig(lam=1e-3, iters=11, backend="jnp")
     clf.fit(x, labels)  # lam is traced; same shapes -> cache hit
-    assert falkon_mod._FUSED_FIT_TRACES == t0
+    assert spans.retraces("falkon.fused_fit") == t0
     assert clf.centers_ is centers
 
 

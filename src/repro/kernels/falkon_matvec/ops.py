@@ -4,11 +4,15 @@
 (K_nM V — predict / KRR forward) are the operators
 ``repro.core.backend.PallasBackend`` serves to ``repro.core.falkon``; all
 pad internally to tile boundaries. Every wrapper accepts a single vector
-(the classic FALKON shapes) or an (·, k) multi-RHS panel; panels are padded
-up to the 128-lane tile width, streamed through the panel kernels in
-falkon_matvec.py — one Gram tile evaluation for every column — and sliced
-back. ``bf16=True`` selects the mixed-precision tile path (bf16 MXU
-operands, fp32 accumulation — see falkon_matvec.py).
+(the classic FALKON shapes) or an (·, k) multi-RHS panel. The path follows
+the live column count: ``falkon_matvec`` and ``knm_t`` stream one column
+(a vector or an (·, 1) panel) as a lane-major row through their VPU
+kernels; k >= 2 columns, the masked matvec and ``knm_matvec`` pad the
+panel up to the 128-lane tile width and stream it through the panel
+kernels — one Gram tile evaluation for every column — and slice it back
+(falkon_matvec.py). ``runtime.spans.taken("kernels")`` counts each pick.
+``bf16=True`` selects the mixed-precision tile path (bf16 MXU operands,
+fp32 accumulation — see falkon_matvec.py).
 """
 from __future__ import annotations
 
@@ -16,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from ...families import get_family
+from ...runtime import spans
 from ..common import default_interpret, pad_dim, round_up
 from .falkon_matvec import (falkon_matvec_masked_pallas, falkon_matvec_pallas,
                             knm_matvec_pallas, knm_t_pallas)
@@ -41,6 +46,21 @@ def _unpanel(out: jax.Array, k_or_none: int | None) -> jax.Array:
     return out[:, 0] if k_or_none is None else out[:, :k_or_none]
 
 
+def _rhs(v: jax.Array, to: int, op: str):
+    """(kernel operand, unpack) for a right-hand side zero-padded along axis
+    0 to ``to``. One live column ((·,) or (·, 1)) goes to the VPU kernels as
+    a (1, to) row; a panel of k >= 2 columns is lane-padded. ``unpack(out,
+    size)`` slices the kernel output back to ``size`` rows in ``v``'s shape.
+    The pick is counted as ``<op>.vector`` or ``<op>.panel``."""
+    if v.ndim == 1 or v.shape[1] == 1:
+        spans.took(op + ".vector")
+        return (pad_dim(v.reshape(1, v.shape[0]), 1, to),
+                lambda out, size: out[0, :size].reshape((size,) + v.shape[1:]))
+    spans.took(op + ".panel")
+    return (_as_panel(pad_dim(v, 0, to))[0],
+            lambda out, size: _unpanel(out[:size], v.shape[1]))
+
+
 def falkon_matvec(x: jax.Array, z: jax.Array, v: jax.Array, sigma: float = 1.0, *,
                   kind: str = "gaussian", bn: int = 512,
                   interpret: bool | None = None, bf16: bool = False,
@@ -61,12 +81,13 @@ def falkon_matvec(x: jax.Array, z: jax.Array, v: jax.Array, sigma: float = 1.0, 
     zp = pad_dim(pad_dim(z, 0, round_up(m, 128)), 1, dp)
     # padded Z rows are the all-zeros point; its kernel values are nonzero but
     # v is zero-padded so they never enter t, and we slice r back to (m,).
-    vp, squeeze = _as_panel(pad_dim(v, 0, round_up(m, 128)))
     if mask is None:
-        out = falkon_matvec_pallas(xp, zp, vp, float(_inv_scale(kind, sigma)),
+        vk, unpack = _rhs(v, round_up(m, 128), "kernels.falkon_matvec")
+        out = falkon_matvec_pallas(xp, zp, vk, float(_inv_scale(kind, sigma)),
                                    kind=kind, bn=bn, n_valid=n,
                                    interpret=interpret, bf16=bf16)
-        return _unpanel(out[:m], None if squeeze else v.shape[1])
+        return unpack(out, m)
+    vp, squeeze = _as_panel(pad_dim(v, 0, round_up(m, 128)))
     # zero-padded mask rows/columns: padded rows are killed by n_valid anyway
     # and padded v columns are zero, so the pad value never reaches the output.
     if mask.ndim == 1 and v.ndim == 2:
@@ -109,10 +130,10 @@ def knm_t(x: jax.Array, z: jax.Array, y: jax.Array, sigma: float = 1.0, *,
     dp = round_up(d, 128)
     xp = pad_dim(pad_dim(x, 0, round_up(n, bn)), 1, dp)
     zp = pad_dim(pad_dim(z, 0, round_up(m, 128)), 1, dp)
-    yp, squeeze = _as_panel(pad_dim(y, 0, round_up(n, bn)))
-    out = knm_t_pallas(xp, zp, yp, float(_inv_scale(kind, sigma)), kind=kind, bn=bn,
+    yk, unpack = _rhs(y, round_up(n, bn), "kernels.knm_t")
+    out = knm_t_pallas(xp, zp, yk, float(_inv_scale(kind, sigma)), kind=kind, bn=bn,
                        n_valid=n, interpret=interpret, bf16=bf16)
-    return _unpanel(out[:m], None if squeeze else y.shape[1])
+    return unpack(out, m)
 
 
 def knm_matvec(x: jax.Array, z: jax.Array, alpha: jax.Array, sigma: float = 1.0, *,

@@ -1,4 +1,4 @@
-"""Program spans and retrace counters, on the profiler's clock.
+"""Program spans, retrace counters and path counters, on the profiler's clock.
 
 ``span(name, **meta)`` is a ``jax.profiler.TraceAnnotation`` named
 ``repro.<name>``: under an active profiler session (``jax.profiler.trace``)
@@ -16,6 +16,12 @@ body (a host-driven backend's ladder phase) is no trace and marks nothing.
 ``falkon.cg`` wraps an eager ``lax.fori_loop``, which traces its body anew
 on every call: a host-driven fit marks it every fit, and the span covers
 the trace, the compile or cache load and the dispatch, not the loop's run.
+
+``took(name)`` counts one pick of a code path where the program chooses
+between two by the shapes it is given (at trace time, like a retrace, so a
+loop traced once counts once); ``taken(name)`` reads it. The fused K_nM
+operators count ``kernels.falkon_matvec.{vector,panel}`` and
+``kernels.knm_t.{vector,panel}``: one live column or a multi-column panel.
 
 Spans of the fit path and the ladder:
 
@@ -37,6 +43,7 @@ import jax
 PREFIX = "repro."
 
 _RETRACES: Counter = Counter()
+_PATHS: Counter = Counter()
 _LOCK = threading.Lock()  # a ladder may trace on a background thread
 
 
@@ -55,9 +62,24 @@ def retrace(name: str):
         yield
 
 
+def _under(counter: Counter, name: str) -> int:
+    with _LOCK:
+        return sum(c for k, c in counter.items()
+                   if k == name or k.startswith(name + "."))
+
+
 def retraces(name: str) -> int:
     """Traces counted under ``name`` and under every ``name.<sub>``:
     ``retraces("bless")`` sums the ladder's phases."""
+    return _under(_RETRACES, name)
+
+
+def took(name: str) -> None:
+    """Count one pick of the code path ``name``."""
     with _LOCK:
-        return sum(c for k, c in _RETRACES.items()
-                   if k == name or k.startswith(name + "."))
+        _PATHS[name] += 1
+
+
+def taken(name: str) -> int:
+    """Picks counted under ``name`` and under every ``name.<sub>``."""
+    return _under(_PATHS, name)

@@ -45,7 +45,8 @@ def test_quadform_shapes(n, m):
     np.testing.assert_allclose(out, ref, rtol=3e-5, atol=3e-4)
 
 
-@pytest.mark.parametrize("n,m,d,bn", [(512, 128, 128, 256), (700, 130, 17, 256), (256, 515, 8, 128)])
+@pytest.mark.parametrize("n,m,d,bn", [(512, 128, 128, 256), (700, 130, 17, 256), (256, 515, 8, 128),
+                                     (600, 200, 90, 256)])
 def test_falkon_matvec_shapes(n, m, d, bn):
     x = jax.random.normal(jax.random.PRNGKey(0), (n, d))
     z = jax.random.normal(jax.random.PRNGKey(1), (m, d))
@@ -66,6 +67,39 @@ def test_falkon_matvec_all_families(kind):
     out = falkon_matvec(x, z, v, 1.5, kind=kind, interpret=True, bn=256)
     ref = falkon_matvec_ref(x, z, v, float(get_family(kind).inv_scale(1.5)), kind=kind)
     np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4 * float(jnp.abs(ref).max()))
+
+
+@pytest.mark.parametrize("n,m,d", [(700, 130, 17), (600, 200, 90)])
+@pytest.mark.parametrize("bf16", [False, True])
+@pytest.mark.parametrize("kind", ["gaussian", "laplacian", "linear", "matern32", "cauchy"])
+@pytest.mark.parametrize("op", ["falkon_matvec", "knm_t"])
+def test_vector_path_matches_panel_column(op, kind, bf16, n, m, d):
+    """One live column takes the VPU kernels; it agrees with column 0 of a
+    2-column panel (the MXU kernels) on the same inputs, with or without
+    bf16 cross products, and with the fp32 oracle when bf16 is off."""
+    from repro.families import get_family, kernel_family_names
+    from repro.kernels.falkon_matvec import ops
+    from repro.kernels.falkon_matvec.ref import knm_t_ref
+
+    assert kind in kernel_family_names()
+    x = jax.random.normal(jax.random.PRNGKey(0), (n, d)) / np.sqrt(d)
+    z = jax.random.normal(jax.random.PRNGKey(1), (m, d)) / np.sqrt(d)
+    rows = m if op == "falkon_matvec" else n
+    v = jax.random.normal(jax.random.PRNGKey(2), (rows,))
+    w = jax.random.normal(jax.random.PRNGKey(3), (rows,))
+    fn = getattr(ops, op)
+    kw = dict(kind=kind, interpret=True, bn=256, bf16=bf16)
+    vec = fn(x, z, v, 1.5, **kw)
+    col = fn(x, z, v[:, None], 1.5, **kw)
+    panel = fn(x, z, jnp.stack([v, w], 1), 1.5, **kw)
+    assert vec.shape == (m,) and col.shape == (m, 1) and panel.shape == (m, 2)
+    scale = float(jnp.abs(panel[:, 0]).max())
+    np.testing.assert_allclose(vec, panel[:, 0], rtol=1e-5, atol=1e-5 * scale)
+    np.testing.assert_array_equal(col[:, 0], vec)
+    if not bf16:
+        ref = (falkon_matvec_ref if op == "falkon_matvec" else knm_t_ref)(
+            x, z, v, float(get_family(kind).inv_scale(1.5)), kind=kind)
+        np.testing.assert_allclose(vec, ref, rtol=1e-4, atol=1e-4 * float(jnp.abs(ref).max()))
 
 
 @pytest.mark.parametrize(
@@ -108,7 +142,7 @@ def test_falkon_matvec_plugs_into_cg():
     assert float(jnp.linalg.norm(pf - pn) / jnp.linalg.norm(pn)) < 1e-3
 
 
-@pytest.mark.parametrize("n,m,d", [(512, 128, 64), (700, 130, 17)])
+@pytest.mark.parametrize("n,m,d", [(512, 128, 64), (700, 130, 17), (600, 200, 90)])
 def test_knm_t_kernel_shapes(n, m, d):
     from repro.kernels.falkon_matvec.ops import knm_t
     from repro.kernels.falkon_matvec.ref import knm_t_ref

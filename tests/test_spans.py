@@ -11,7 +11,7 @@ import pytest
 from jax.profiler import ProfileData
 
 from repro.api import FalkonRegressor, FitConfig, UniformSampler, make_kernel
-from repro.core import bless, bless_r, falkon_fit
+from repro.core import PallasBackend, bless, bless_r, falkon_fit
 from repro.core import falkon as falkon_mod
 from repro.runtime import spans
 
@@ -93,6 +93,29 @@ def test_host_eigh_span_lies_inside_its_fit(tmp_path, monkeypatch):
     (eigh,) = _named(events, "repro.precond.eigh")
     assert eigh[3] == {"m": 44}
     assert fit[1] <= eigh[1] < eigh[2] <= fit[2]
+
+
+def test_one_output_fit_takes_the_vector_kernels_and_a_panel_does_not():
+    """The fused K_nM operators pick their path from the live column count:
+    a single-output fit runs the VPU (vector) kernels, a two-output fit the
+    MXU panel kernels, and each pick is counted."""
+    x, y = _data(n=300)
+    cs = UniformSampler(m=40).sample(jax.random.PRNGKey(5), x, KERN)
+    cfg = FitConfig(lam=1e-3, iters=3, backend=PallasBackend(interpret=True))
+    ops = ("kernels.falkon_matvec", "kernels.knm_t")
+
+    def picks(fit):
+        before = {op + p: spans.taken(op + p) for op in ops for p in (".vector", ".panel")}
+        fit()
+        return {k: spans.taken(k) - c for k, c in before.items()}
+
+    one = picks(lambda: FalkonRegressor(kernel=KERN, config=cfg).fit(x, y, center_set=cs))
+    two = picks(lambda: FalkonRegressor(kernel=KERN, config=cfg).fit(
+        x, jnp.stack([y, -y], 1), center_set=cs))
+    for op in ops:
+        assert one[op + ".vector"] >= 1 and one[op + ".panel"] == 0
+        assert two[op + ".panel"] >= 1 and two[op + ".vector"] == 0
+    assert spans.taken("kernels") >= sum(one.values()) + sum(two.values())
 
 
 @pytest.mark.parametrize("ladder", [bless, bless_r])

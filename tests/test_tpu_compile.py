@@ -6,6 +6,9 @@ Each test lowers one ``PallasBackend`` contraction with ``interpret=False``
 at the bring-up shapes (n = 2^20 rows, d = 128, M = 8192; M = 1024 for the
 fused scorer) for one chip of a ``v5e:2x2`` topology, compiles it with the
 TPU compiler installed beside JAX, and asserts the Mosaic kernel is there.
+The K_nM operators compile on both of their paths: one live column (the
+VPU kernels, also at susy.fit's d = 18, M = 5632 and at M = 16384) and a
+two-column panel (the MXU kernels).
 Nothing runs, so no chip is needed; where the topology cannot be described
 the fixture skips.
 
@@ -23,6 +26,7 @@ from repro.kernels.quadform import ops as quadform_ops
 N, D, M = 1 << 20, 128, 8192
 M_FUSED = 1024  # the fused scorer's largest center buffer (rls_score.ops.MAX_FUSED_M)
 N_SCORE = 1 << 17  # candidate rows of one composed ladder level: (N_SCORE, M) fits HBM
+D_SUSY, M_SUSY, M_WIDE = 18, 5632, 16384  # susy.fit's width and centers; the widest M
 
 
 @pytest.fixture(scope="module")
@@ -69,13 +73,27 @@ def _cases(s):
                           s(N, D), s(M, D), s(M)),
         "falkon_matvec_masked": (lambda x, z, v, m: be.knm_quadratic(kern, x, z, mask=m)(v),
                                  s(N, D), s(M, D), s(M), s(N)),
+        "falkon_matvec_panel": (lambda x, z, v: be.knm_quadratic(kern, x, z)(v),
+                                s(N, D), s(M, D), s(M, 2)),
+        "falkon_matvec_susy": (lambda x, z, v: be.knm_quadratic(kern, x, z)(v),
+                               s(N, D_SUSY), s(M_SUSY, D_SUSY), s(M_SUSY)),
+        "falkon_matvec_wide": (lambda x, z, v: be.knm_quadratic(kern, x, z)(v),
+                               s(N, D_SUSY), s(M_WIDE, D_SUSY), s(M_WIDE)),
         "knm_t": (lambda x, z, y: be.knm_t(kern, x, z, y), s(N, D), s(M, D), s(N)),
+        "knm_t_panel": (lambda x, z, y: be.knm_t(kern, x, z, y), s(N, D), s(M, D), s(N, 2)),
+        "knm_t_susy": (lambda x, z, y: be.knm_t(kern, x, z, y),
+                       s(N, D_SUSY), s(M_SUSY, D_SUSY), s(N)),
+        "knm_t_wide": (lambda x, z, y: be.knm_t(kern, x, z, y),
+                       s(N, D_SUSY), s(M_WIDE, D_SUSY), s(N)),
         "knm_matvec": (lambda x, z, a: be.knm_matvec(kern, x, z, a), s(N, D), s(M, D), s(M)),
     }
 
 
 @pytest.mark.parametrize("name", ["rls_score", "quadform", "gram", "falkon_matvec",
-                                  "falkon_matvec_masked", "knm_t", "knm_matvec"])
+                                  "falkon_matvec_masked", "knm_t", "knm_matvec",
+                                  "falkon_matvec_panel", "falkon_matvec_susy",
+                                  "falkon_matvec_wide", "knm_t_panel", "knm_t_susy",
+                                  "knm_t_wide"])
 def test_kernel_compiles_for_v5e(shapes, name):
     fn, *args = _cases(shapes)[name]
     compiled = jax.jit(fn).lower(*args).compile()

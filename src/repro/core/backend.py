@@ -52,6 +52,10 @@ from .leverage import _chol_with_jitter
 Array = jax.Array
 KnmQuadraticOp = Callable[[Array], Array]
 
+#: Precision of every float32 product against a Gram block: a TPU runs a
+#: DEFAULT-precision float32 dot as one bf16 pass.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 # ---------------------------------------------------------------------------
 # Block-size tables.
 #
@@ -92,8 +96,9 @@ def _stream_min_rows() -> int:
     Where the device reports its memory (TPU, GPU) X may take a quarter of
     it at 512 B a row — fp32 with d padded to the 128 lanes the Pallas
     tiles use — leaving the rest to the padded copies and (block, M) Gram
-    tiles; a 16 GB v5e keeps ~7.7M rows in core. Devices that report
-    nothing (the CPU backend) keep the fixed ``_STREAM_MIN_ROWS``.
+    tiles; a 16 GB v5e (``bytes_limit`` 16,909,336,064) keeps 8,256,511
+    rows in core. Devices that report nothing (the CPU backend) keep the
+    fixed ``_STREAM_MIN_ROWS``.
     """
     limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
     return int(limit) // (4 * 512) if limit else _STREAM_MIN_ROWS
@@ -137,6 +142,10 @@ class Backend:
     #: (including the kernel bandwidth). Non-jit-safe backends are driven by
     #: the host-level code paths instead.
     jit_safe: ClassVar[bool] = False
+    #: True if each call of its K_nM operators is a pass dispatched from the
+    #: host: a CG loop then calls the operator once per iteration instead of
+    #: tracing it into one compiled loop (``falkon.cg``).
+    host_loop: ClassVar[bool] = False
 
     def gram_block(self, kernel: Kernel, x: Array, z: Array) -> Array:
         """K(X, Z) of shape (n, m)."""
@@ -302,10 +311,10 @@ def _jnp_knm_matvec(kernel: Kernel, x: Array, z: Array, v: Array, *,
     """
     n = x.shape[0]
     if n <= block:
-        return kernel.cross(x, z) @ v
+        return jnp.matmul(kernel.cross(x, z), v, precision=_HIGHEST)
     pad = (-n) % block
     xp = jnp.pad(x, ((0, pad), (0, 0)))
-    out = jax.lax.map(lambda xb: kernel.cross(xb, z) @ v,
+    out = jax.lax.map(lambda xb: jnp.matmul(kernel.cross(xb, z), v, precision=_HIGHEST),
                       xp.reshape(-1, block, x.shape[1]))
     return out.reshape((-1,) + v.shape[1:])[:n]
 

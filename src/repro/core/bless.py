@@ -163,6 +163,9 @@ def _dedup_centers(centers: CenterSet, lamn: Array, dbuf: int):
     return dd_idx, dd_mask, dd_reg
 
 
+_dedup_centers_jit = partial(jax.jit, static_argnames=("dbuf",))(_dedup_centers)
+
+
 def _rls_dedup(kernel, x_cand, cand_mask, x_all, centers, lamn, *, backend, dbuf):
     """Eq. 3 scores of candidates against a (possibly multiset) center set,
     deduplicated internally, through ``backend.rls_scores``. Clipped to
@@ -170,15 +173,18 @@ def _rls_dedup(kernel, x_cand, cand_mask, x_all, centers, lamn, *, backend, dbuf
 
     Host-resident ``x_all`` (a ``repro.stream.ChunkStore``) takes a Python
     branch instead of ``lax.cond``: the cond traces BOTH branches, and a
-    traced center gather would force the whole store onto the device. Only
-    reachable on non-jit-safe backends (the stream driver), so the jitted
-    ladder phases never see it; the empty-center case routes through
-    ``rls_scores`` with an all-masked buffer (exactly K_ii / lamn) so a
-    chunked ``x_cand`` never meets a raw ``kernel.diag``.
+    traced center gather would force the whole store onto the device. So
+    does a backend that dispatches its own compiled passes
+    (``Backend.host_loop``): an eager cond would trace the pass anew, and
+    compile it, at every level. Only reachable on non-jit-safe backends
+    (the stream driver), so the jitted ladder phases never see it; the
+    empty-center case routes through ``rls_scores`` with an all-masked
+    buffer (exactly K_ii / lamn) so a chunked ``x_cand`` never meets a raw
+    ``kernel.diag``.
     """
-    if not isinstance(x_all, jax.Array):
+    if not isinstance(x_all, jax.Array) or backend.host_loop:
         if int(centers.count) > 0:
-            dd_idx, dd_mask, dd_reg = _dedup_centers(centers, lamn, dbuf)
+            dd_idx, dd_mask, dd_reg = _dedup_centers_jit(centers, lamn, dbuf=dbuf)
             s = backend.rls_scores(kernel, x_cand, x_all[dd_idx], dd_mask,
                                    dd_reg, lamn)
         else:

@@ -65,10 +65,10 @@ from .leverage import CenterSet  # noqa: F401 — re-exported for callers
 
 Array = jax.Array
 
-#: Precision of the M-space algebra (the preconditioner, K_MM u). TPU runs
-#: a DEFAULT-precision fp32 matmul as one bf16 pass; with a (M, k) panel
-#: that would put ~1e-3 noise into every CG step. These O(M^2) products
-#: cost nothing next to the O(n M) K_nM sweep, so they run at full fp32.
+#: Precision of the M-space algebra (the preconditioner, K_MM u) and of the
+#: jnp streamer's contractions against each Gram block. TPU runs a
+#: DEFAULT-precision fp32 matmul as one bf16 pass; with a (M, k) panel that
+#: would put ~1e-3 noise into every CG step.
 _M_SPACE = jax.lax.Precision.HIGHEST
 
 
@@ -191,10 +191,10 @@ def local_knm_quadratic(kernel: Kernel, x: Array, z: Array, *, block: int = 8192
         def body(carry, args):
             xb, mb, cb = args
             g = kernel.cross(xb, z) * mb[:, None]
-            t = g @ v
+            t = jnp.matmul(g, v, precision=_M_SPACE)
             if cb is not None:
                 t = t * (cb if t.ndim == cb.ndim else cb[:, None])
-            return carry + g.T @ t, None
+            return carry + jnp.matmul(g.T, t, precision=_M_SPACE), None
 
         out, _ = jax.lax.scan(body, jnp.zeros((m,) + v.shape[1:], v.dtype),
                               (xp.reshape(nb, block, -1), valid,
@@ -223,7 +223,7 @@ def local_knm_t(kernel: Kernel, x: Array, z: Array, y: Array, *, block: int = 81
 
     def body(carry, args):
         xb, yb = args
-        return carry + kernel.cross(xb, z).T @ yb, None
+        return carry + jnp.matmul(kernel.cross(xb, z).T, yb, precision=_M_SPACE), None
 
     out, _ = jax.lax.scan(body, jnp.zeros((m,) + y.shape[1:], x.dtype),
                           (xp.reshape(nb, block, -1),
@@ -246,7 +246,7 @@ _CG_FREEZE_REL = 1e-14
 
 def cg(matvec: Callable[[Array], Array], b: Array, iters: int,
        callback: Callable[[int, Array], None] | None = None,
-       trajectory: bool = False) -> Array | tuple[Array, Array]:
+       trajectory: bool = False, host: bool = False) -> Array | tuple[Array, Array]:
     """CG on SPD ``matvec``; fixed iteration count (paper uses t ~ log n).
 
     ``b`` may be a single right-hand side (q,) or an (q, k) panel — the
@@ -265,10 +265,12 @@ def cg(matvec: Callable[[Array], Array], b: Array, iters: int,
     recorded residual simply plateaus — the recorded value stays exact.)
 
     With ``callback`` the loop runs on host (per-iteration metrics for the
-    Fig. 4/5 analogues); otherwise it is a single jitted lax.fori_loop,
-    traced anew on every call (``falkon.cg`` in ``runtime.spans``): called
-    outside a jit, as the host-driven fit calls it, it is then compiled or
-    loaded from the persistent cache again.
+    Fig. 4/5 analogues), and so it does with ``host=True``: a ``matvec``
+    that dispatches its own compiled pass per call (``Backend.host_loop``)
+    is called once per iteration, never traced. Otherwise it is a single
+    jitted lax.fori_loop, traced anew on every call (``falkon.cg`` in
+    ``runtime.spans``): called outside a jit, as the host-driven fit calls
+    it, it is then compiled or loaded from the persistent cache again.
     """
     rs0 = jnp.sum(b * b, axis=0)
 
@@ -286,12 +288,13 @@ def cg(matvec: Callable[[Array], Array], b: Array, iters: int,
         return beta, r, p, jnp.where(active, rs_new, rs)
 
     state = (jnp.zeros_like(b), b, b, rs0)
-    if callback is not None:
+    if callback is not None or host:
         resid = [rs0]
         for i in range(iters):
             state = step(state)
             resid.append(state[3])
-            callback(i, state[0])
+            if callback is not None:
+                callback(i, state[0])
         if trajectory:
             return state[0], jnp.stack(resid)
         return state[0]
@@ -627,7 +630,8 @@ def falkon_fit(
         def cb(i, beta):  # noqa: E731 — host-side metric hook
             callback(i, FalkonModel(centers=centers, alpha=prec.apply(beta),
                                     kernel=kernel, backend=backend))
-    beta, resid = cg(matvec, b, iters, callback=cb, trajectory=True)
+    beta, resid = cg(matvec, b, iters, callback=cb, trajectory=True,
+                     host=backend.host_loop)
     alpha = prec.apply(beta)
     if check_finite:
         health.check_finite(alpha, "falkon_fit alpha")

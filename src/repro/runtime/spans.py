@@ -22,6 +22,9 @@ between two by the shapes it is given (at trace time, like a retrace, so a
 loop traced once counts once); ``taken(name)`` reads it. The fused K_nM
 operators count ``kernels.falkon_matvec.{vector,panel}`` and
 ``kernels.knm_t.{vector,panel}``: one live column or a multi-column panel.
+``count(name, amount)`` adds to a counter that ``counted(name)`` reads: the
+streamed passes count ``stream.chunks`` (chunk steps a pass runs) and
+``stream.h2d_bytes`` (bytes a host source put on the device).
 
 Spans of the fit path and the ladder:
 
@@ -29,8 +32,11 @@ Spans of the fit path and the ladder:
   * ``repro.precond.eigh``       the preconditioner's host LAPACK ``eigh``;
   * ``repro.bless.level``        one BLESS / BLESS-R ladder level on the host;
   * ``repro.bless.sync``         that level's blocking host fetch;
+  * ``repro.stream.pass``        one streamed K_nM pass (op, rows, chunk, m,
+                                 k), from its dispatch to its result;
   * ``repro.retrace.<name>``     ``falkon.fused_fit``, ``falkon.cg``,
-                                 ``bless.<phase>``, ``online.acc_solve``.
+                                 ``bless.<phase>``, ``online.acc_solve``,
+                                 ``stream.device_pass``, ``stream.chunk_step``.
 """
 from __future__ import annotations
 
@@ -44,6 +50,7 @@ PREFIX = "repro."
 
 _RETRACES: Counter = Counter()
 _PATHS: Counter = Counter()
+_COUNTS: Counter = Counter()
 _LOCK = threading.Lock()  # a ladder may trace on a background thread
 
 
@@ -83,3 +90,14 @@ def took(name: str) -> None:
 def taken(name: str) -> int:
     """Picks counted under ``name`` and under every ``name.<sub>``."""
     return _under(_PATHS, name)
+
+
+def count(name: str, amount: int = 1) -> None:
+    """Add ``amount`` to the counter ``name``."""
+    with _LOCK:
+        _COUNTS[name] += amount
+
+
+def counted(name: str) -> int:
+    """The counter ``name`` plus every ``name.<sub>``."""
+    return _under(_COUNTS, name)

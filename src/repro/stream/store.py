@@ -29,6 +29,8 @@ import threading
 import jax
 import numpy as np
 
+from ..runtime import spans
+
 Array = jax.Array
 
 #: Default rows per device chunk by platform. Sized so a (chunk, M) Gram
@@ -272,7 +274,8 @@ def device_chunks(x, aux=None, *, chunk: int | None = None):
     Host sources (ChunkStore / numpy) are copied chunk-by-chunk with
     ``jax.device_put``; the put for chunk i+1 is issued before chunk i is
     yielded, so the copy overlaps the consumer's compute under async
-    dispatch, and the tracker never sees more than two chunks resident.
+    dispatch, and the tracker never sees more than two chunks resident;
+    the bytes put count as ``stream.h2d_bytes`` (``runtime.spans``).
     Device-resident ``x`` (small-n parity paths) is sliced lazily with no
     copies and no accounting.
     """
@@ -289,9 +292,11 @@ def device_chunks(x, aux=None, *, chunk: int | None = None):
         if host:
             xb = jax.device_put(xb)
             _TRACKER.add(_nbytes(xb))
+            spans.count("stream.h2d_bytes", _nbytes(xb))
             if ab is not None and isinstance(ab, np.ndarray):
                 ab = jax.device_put(ab)
                 _TRACKER.add(_nbytes(ab))
+                spans.count("stream.h2d_bytes", _nbytes(ab))
         return xb, ab
 
     def drop(pair):

@@ -157,3 +157,25 @@ def test_solver_import_loads_no_lm_runtime():
                          capture_output=True, text=True).stdout.split()
     assert "repro.runtime.spans" in out
     assert not {"repro.runtime.monitor", "repro.runtime.compress"} & set(out)
+
+
+@pytest.mark.parametrize("source", ["device", "store"])
+def test_streamed_fit_spans_each_pass_with_its_shape(tmp_path, source):
+    """A fit through ``StreamBackend`` is one ``repro.stream.pass`` span per
+    pass, in order: the right-hand side, then one CG operator pass per
+    iteration, each with (op, rows, chunk, m, k), inside the fit's span."""
+    from repro.stream import ChunkStore, StreamBackend
+
+    x, y = _data(n=333, d=4, seed=6)
+    data = x if source == "device" else ChunkStore(jax.device_get(x), chunk=100)
+    cs = UniformSampler(m=24).sample(jax.random.PRNGKey(3), x, KERN)
+    be = StreamBackend(inner=PallasBackend(interpret=True), chunk=100)
+    est = FalkonRegressor(kernel=KERN, config=FitConfig(lam=1e-3, iters=4, backend=be))
+    _, events = _profiled(tmp_path, lambda: est.fit(data, y, center_set=cs).model_.alpha)
+    (fit,) = _named(events, "repro.fit")
+    passes = _named(events, "repro.stream.pass")
+    assert [p[3]["op"] for p in passes] == ["knm_t"] + ["knm_quadratic"] * 4
+    for p in passes:
+        assert {k: p[3][k] for k in ("rows", "chunk", "m", "k")} == {
+            "rows": 333, "chunk": 100, "m": 24, "k": 1}
+        assert fit[1] <= p[1] < p[2] <= fit[2]

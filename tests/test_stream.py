@@ -13,10 +13,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (JnpBackend, default_backend, falkon_fit, make_kernel,
-                        resolve_backend)
+from repro.core import (JnpBackend, PallasBackend, default_backend, falkon_fit,
+                        make_kernel, resolve_backend)
 from repro.core.bless import bless
 from repro.core.leverage import approx_rls_all, uniform_center_set
+from repro.runtime import spans
 from repro.stream import (ChunkStore, StreamBackend, device_chunks,
                           peak_device_bytes, reset_peak_device_bytes)
 
@@ -237,24 +238,29 @@ def test_peak_memory_stays_far_below_knm():
 
 
 def test_compiled_chunk_step_memory_is_n_independent():
-    """Cost-analysis proof: the compiled per-chunk program's temp footprint
-    depends on (chunk, M), never on n — streaming 10x the rows reuses the
-    same executable with the same temporary allocations."""
-    from repro.stream.backend import _quad_chunk
+    """Cost-analysis proof: the compiled pass's temp footprint depends on
+    (chunk, M), never on n — streaming 10x the rows of a device-resident X
+    runs the same chunk step with the same temporary allocations."""
+    from repro.stream.backend import _device_pass, _quad_chunk, _static_kernel
 
     m, d, chunk = 32, 6, 512
     z = jnp.zeros((m, d), jnp.float32)
     v = jnp.zeros((m,), jnp.float32)
     acc = jnp.zeros((m,), jnp.float32)
-    xb = jnp.zeros((chunk, d), jnp.float32)
-    step = jax.jit(lambda *a: _quad_chunk(KERN, *a, inner=JNP))
-    compiled = step.lower(xb, z, v, acc).compile()
-    analysis = compiled.memory_analysis()
-    if analysis is None:  # platform without memory analysis
-        pytest.skip("memory_analysis unavailable")
-    temp = int(analysis.temp_size_in_bytes)
+    temps = []
+    for n in (4 * chunk + 100, 40 * chunk + 100):
+        x = jnp.zeros((n, d), jnp.float32)
+        compiled = _device_pass.lower(
+            x, None, (z, v), acc, step=_quad_chunk, kernel=_static_kernel(KERN),
+            inner=StreamBackend(inner=JNP)._chunk_inner(chunk), chunk=chunk,
+            rows=False).compile()
+        analysis = compiled.memory_analysis()
+        if analysis is None:  # platform without memory analysis
+            pytest.skip("memory_analysis unavailable")
+        temps.append(int(analysis.temp_size_in_bytes))
     # the footprint is a few (chunk, m) tiles — nothing anywhere near (n, m)
-    assert temp <= 4 * chunk * m * 8
+    assert temps[0] == temps[1]
+    assert temps[1] <= 4 * chunk * m * 8
 
 
 def test_gram_block_materialization_guard():
@@ -414,3 +420,178 @@ def test_estimator_front_door_accepts_store():
     assert pred.shape == (n,)
     ref = est.predict(jnp.asarray(x))
     _close(pred, ref, tol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# The fused streamed pass: inner fused operators, one program per shape,
+# HIGHEST-precision chunk steps
+# ---------------------------------------------------------------------------
+
+PALLAS = PallasBackend(interpret=True)
+
+
+def _nystrom_reference(x, y, z, lam):
+    """Plain float64 Nystrom KRR on centers z (A = I): predictions at x of
+    alpha = (K_nM^T K_nM + lam n K_MM)^+ K_nM^T y."""
+    x, y, z = (np.asarray(a, np.float64) for a in (x, y, z))
+
+    def gauss(a, b):
+        d2 = (a * a).sum(1)[:, None] + (b * b).sum(1)[None, :] - 2 * a @ b.T
+        return np.exp(-np.maximum(d2, 0) / (2 * 1.5 ** 2))
+
+    knm, kmm = gauss(x, z), gauss(z, z)
+    h = knm.T @ knm + lam * x.shape[0] * kmm
+    return knm @ (np.linalg.pinv(h, rcond=1e-12) @ (knm.T @ y))
+
+
+@pytest.mark.parametrize("source", ["device", "store"])
+@pytest.mark.parametrize("k", [1, 3])
+def test_fused_stream_fit_matches_plain_reference(k, source):
+    """A fit through ``StreamBackend(inner=PallasBackend)`` on 4 chunks and
+    a ragged 37-row tail lands on the plain float64 Nystrom solution."""
+    chunk, m, lam = 96, 24, 1e-3
+    n = 4 * chunk + 37
+    x, ym = _xy(n, d=5, k=3, seed=31)
+    y = ym[:, 0] if k == 1 else ym
+    z = jnp.asarray(x[:m])
+    data = jnp.asarray(x) if source == "device" else ChunkStore(x, chunk=chunk)
+    mod = falkon_fit(KERN, data, jnp.asarray(y), z, lam, iters=40,
+                     backend=StreamBackend(inner=PALLAS, chunk=chunk))
+    want = _nystrom_reference(x, y, x[:m], lam)
+    got = np.asarray(mod.predict(jnp.asarray(x), backend=JNP))
+    assert got.shape == want.shape
+    assert np.linalg.norm(got - want) / np.linalg.norm(want) < 1e-3
+
+
+@pytest.mark.parametrize("source", ["device", "store"])
+def test_stream_pass_runs_the_fused_kernels_per_chunk(source):
+    """Each CG pass contracts every chunk through the inner backend's fused
+    VPU kernels: the vector path is picked, every chunk is counted, and
+    only host sources put bytes on the device."""
+    chunk, m, iters = 64, 20, 4
+    n = 5 * chunk + 11
+    x, y = _xy(n, d=3, seed=37)
+    data = jnp.asarray(x) if source == "device" else ChunkStore(x, chunk=chunk)
+    names = ("kernels.falkon_matvec.vector", "kernels.knm_t.vector", "stream.chunks",
+             "stream.h2d_bytes")
+
+    def read():
+        return {k: spans.taken(k) + spans.counted(k) for k in names}
+
+    before = read()
+    falkon_fit(KERN, data, jnp.asarray(y), jnp.asarray(x[:m]), 1e-3, iters=iters,
+               backend=StreamBackend(inner=PALLAS, chunk=chunk))
+    got = {k: v - before[k] for k, v in read().items()}
+    assert got["kernels.falkon_matvec.vector"] >= 1 and got["kernels.knm_t.vector"] >= 1
+    assert got["stream.chunks"] == 6 * (iters + 1)  # 5 chunks + tail, iters + rhs passes
+    assert got["stream.h2d_bytes"] == (0 if source == "device"
+                                       else (iters + 1) * 4 * n * 3)
+
+
+def _dots(jaxpr):
+    """Every dot_general in ``jaxpr`` and in the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if isinstance(inner, jax.extend.core.Jaxpr):
+                    yield from _dots(inner)
+
+
+def _pass_jaxprs(jaxpr):
+    """The sub-jaxprs of the streamed passes (``_device_pass``) in ``jaxpr``."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("jit", "pjit") and eqn.params["name"] == "_device_pass":
+            yield eqn.params["jaxpr"].jaxpr
+        else:
+            for p in eqn.params.values():
+                inner = getattr(p, "jaxpr", p)
+                if isinstance(inner, jax.extend.core.Jaxpr):
+                    yield from _pass_jaxprs(inner)
+
+
+@pytest.mark.parametrize("inner", ["pallas", "jnp"])
+def test_every_chunk_step_dot_requests_highest(inner):
+    """A DEFAULT float32 dot runs as one bf16 pass on a TPU: every dot of a
+    streamed pass's chunk step, for each operator, asks for HIGHEST."""
+    be = StreamBackend(inner=PALLAS if inner == "pallas" else JNP, chunk=64)
+    n, m = 3 * 64 + 9, 16
+    x, y = _xy(n, d=4, seed=41)
+    x, y = jnp.asarray(x), jnp.asarray(y)
+    z, v = x[:m], jnp.linspace(-1.0, 1.0, m)
+    mask, reg = jnp.ones((m,), bool), jnp.full((m,), 2.0)
+    calls = {
+        "knm_quadratic": lambda: be.knm_quadratic(KERN, x, z)(v),
+        "knm_quadratic_masked": lambda: be.knm_quadratic(KERN, x, z, mask=y > 0)(v),
+        "knm_t": lambda: be.knm_t(KERN, x, z, y),
+        "knm_matvec": lambda: be.knm_matvec(KERN, x, z, jnp.stack([v, v], 1)),
+        "masked_quadform": lambda: be.masked_quadform(KERN, x, z, mask, reg),
+        "rls_scores": lambda: be.rls_scores(KERN, x, z, mask, reg, jnp.float32(2.0)),
+    }
+    for name, call in calls.items():
+        (body,) = _pass_jaxprs(jax.make_jaxpr(call)().jaxpr)
+        dots = list(_dots(body))
+        assert dots, name
+        for eqn in dots:
+            prec = eqn.params["precision"]
+            prec = prec if isinstance(prec, tuple) else (prec, prec)
+            assert prec == (jax.lax.Precision.HIGHEST,) * 2, (name, prec)
+
+
+@pytest.mark.parametrize("source", ["device", "store"])
+def test_traced_programs_do_not_grow_with_chunks(source):
+    """A pass over 3 or 30 chunks traces the same programs: on device one
+    per shape, holding the chunk step twice (loop body and tail) — the chunk
+    loop is a fori_loop, not unrolled; on the host two chunk steps (uniform
+    and tail) shared by every n."""
+    chunk, m = 32, 8
+    name = "stream.device_pass" if source == "device" else "stream.chunk_step"
+    traced, steps = [], []
+    for nb in (3, 30):
+        n = nb * chunk + 5
+        x, _ = _xy(n, d=3, seed=43 + nb)
+        data = jnp.asarray(x) if source == "device" else ChunkStore(x, chunk=chunk)
+        op = StreamBackend(inner=PALLAS, chunk=chunk).knm_quadratic(KERN, data,
+                                                                   jnp.asarray(x[:m]))
+        before = (spans.retraces(name), spans.taken("kernels.falkon_matvec.vector"))
+        for _ in range(3):
+            op(jnp.full((m,), 0.5, jnp.float32))
+        traced.append(spans.retraces(name) - before[0])
+        steps.append(spans.taken("kernels.falkon_matvec.vector") - before[1])
+    if source == "device":
+        assert traced == [1, 1] and steps == [2, 2]
+    else:
+        assert traced[0] <= 2 and traced[1] == 0 and steps[1] == 0
+
+
+def test_scorer_factor_in_host_lapack_matches_the_device_ladder(monkeypatch):
+    """On a TPU the scorers' inverse Cholesky factor runs in host LAPACK
+    (forced here as on a TPU): it agrees with XLA's, and it climbs the same
+    jitter ladder on a singular K_JJ (duplicate centers)."""
+    from repro.stream import backend as sb
+
+    x, _ = _xy(64, d=3, seed=47)
+    z = jnp.asarray(np.concatenate([x[:20], x[:4]]))  # 4 duplicate centers
+    maskf = jnp.ones((24,), jnp.float32)
+    kern = sb._static_kernel(KERN)
+    host = []
+    monkeypatch.setattr(sb, "_host_inv_chol",
+                        lambda a, f=sb._host_inv_chol: host.append(1) or f(a))
+    for i, reg in enumerate((jnp.full((24,), 0.5), jnp.zeros((24,)))):
+        want = sb._inv_chol(z, maskf, reg, kernel=kern, inner=JNP)
+        with monkeypatch.context() as mp:
+            mp.setattr(sb.jax, "default_backend", lambda: "tpu")
+            # a static inner of its own: a trace of its own, on the TPU branch
+            got = sb._inv_chol(z, maskf, reg, kernel=kern, inner=JnpBackend(block=99))
+        assert len(host) == i + 1
+        assert np.isfinite(np.asarray(got)).all()
+        kjj = np.asarray(KERN.cross(z, z), np.float64) + np.diag(np.asarray(reg))
+        # L^{-1} K_JJ L^{-T}: I where the jitter is negligible, 0 on the
+        # duplicates' null space, the same through both factorizations
+        prods = [np.asarray(inv, np.float64) @ kjj @ np.asarray(inv, np.float64).T
+                 for inv in (got, want)]
+        np.testing.assert_allclose(prods[0], prods[1], atol=0.02)
+        if float(reg[0]) > 0:
+            np.testing.assert_allclose(prods[0], np.eye(24), atol=1e-3)

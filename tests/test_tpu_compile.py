@@ -8,7 +8,11 @@ fused scorer) for one chip of a ``v5e:2x2`` topology, compiles it with the
 TPU compiler installed beside JAX, and asserts the Mosaic kernel is there.
 The K_nM operators compile on both of their paths: one live column (the
 VPU kernels, also at susy.fit's d = 18, M = 5632 and at M = 16384) and a
-two-column panel (the MXU kernels).
+two-column panel (the MXU kernels). The streamed passes compile at
+higgs.fit's shapes (n = 1.05e7 rows, d = 28, M = 5632, 65536-row chunks;
+the ladder's last level scores 327680 candidates) as one loop over the
+chunks, within one chip's HBM: the fused kernel appears once for the loop
+body and once for a ragged tail, never once per chunk.
 Nothing runs, so no chip is needed; where the topology cannot be described
 the fixture skips.
 
@@ -27,6 +31,8 @@ N, D, M = 1 << 20, 128, 8192
 M_FUSED = 1024  # the fused scorer's largest center buffer (rls_score.ops.MAX_FUSED_M)
 N_SCORE = 1 << 17  # candidate rows of one composed ladder level: (N_SCORE, M) fits HBM
 D_SUSY, M_SUSY, M_WIDE = 18, 5632, 16384  # susy.fit's width and centers; the widest M
+N_HIGGS, D_HIGGS, CHUNK = 10_500_000, 28, 65536  # higgs.fit: 160 chunks and a 14240-row tail
+R_LADDER = 327_680  # higgs.fit's last ladder level: 3e5 candidates in a quarter-pow2 buffer
 
 
 @pytest.fixture(scope="module")
@@ -98,3 +104,31 @@ def test_kernel_compiles_for_v5e(shapes, name):
     fn, *args = _cases(shapes)[name]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("op", ["knm_quadratic", "knm_t", "rls_scores"])
+def test_streamed_pass_compiles_for_v5e_without_unrolling(shapes, op):
+    """The streamed passes at higgs.fit's shapes, within one chip's HBM: the
+    CG operator and right-hand side over 1.05e7 rows, and the BLESS ladder's
+    last level (327680 candidates against 5632 centers). The fused kernel
+    is there once for the loop body and once for a ragged tail."""
+    from repro.stream import StreamBackend
+
+    be = StreamBackend(inner=PallasBackend(interpret=False), chunk=CHUNK)
+    kern = make_kernel("gaussian", sigma=22.0)
+    z = shapes(M_SUSY, D_HIGGS)
+    if op == "knm_quadratic":
+        n, fn, rest = N_HIGGS, (lambda x, z, v: be.knm_quadratic(kern, x, z)(v)), (shapes(M_SUSY),)
+    elif op == "knm_t":
+        n, fn, rest = N_HIGGS, (lambda x, z, y: be.knm_t(kern, x, z, y)), (shapes(N_HIGGS),)
+    else:  # the scorer's pass alone, given the hoisted inverse factor
+        from repro.stream.backend import _device_pass, _rls_chunk, _static_kernel
+
+        n, rest = R_LADDER, (shapes(M_SUSY), shapes(M_SUSY, M_SUSY))
+        fn = lambda x, z, maskf, inv_l: _device_pass(  # noqa: E731
+            x, None, (z, maskf, inv_l, jnp.float32(1e4)), jnp.zeros((n,)),
+            step=_rls_chunk, kernel=_static_kernel(kern), inner=be.inner, chunk=CHUNK,
+            rows=True)
+    text = jax.jit(fn).lower(shapes(n, D_HIGGS), z, *rest).compile().as_text()
+    calls = 1 + (n % CHUNK > 0)  # the loop body's kernel and the tail's
+    assert text.count('custom_call_target="tpu_custom_call"') == calls

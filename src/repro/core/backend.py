@@ -412,6 +412,16 @@ class PallasBackend(Backend):
                                 interpret=self.interpret, bf16=self.bf16,
                                 mask=mask)
 
+    def knm_operators(self, kernel: Kernel, x: Array, z: Array,
+                      y: Array, *,
+                      mask: Array | None = None) -> tuple[KnmQuadraticOp, Array]:
+        """Both halves over one padding or packing of X and Z."""
+        kind, sigma = _kernel_params(kernel)
+        return falkon_ops.knm_operators(x, z, y, sigma, kind=kind,
+                                        bn=self._matvec_bn(x.shape[0]),
+                                        interpret=self.interpret, bf16=self.bf16,
+                                        mask=mask)
+
     def knm_matvec(self, kernel: Kernel, x: Array, z: Array, v: Array) -> Array:
         """K(X, Z) v fused in VMEM; (M,) -> (n,) or (M, k) -> (n, k)."""
         kind, sigma = _kernel_params(kernel)

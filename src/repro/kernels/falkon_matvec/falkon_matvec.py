@@ -29,7 +29,21 @@ before.
 On GPU the reference FALKON implementation materializes K_nM block-by-block
 in HBM and runs two GEMVs per block (arithmetic intensity ~4 FLOP/B on the
 second pass). Fusing keeps HBM traffic at n*d reads + n*kp (or M*kp) writes
-total; what remains is the cross product (DESIGN.md §2 has the cost model).
+total; what remains is the cross product and the VPU epilogue (DESIGN.md §2
+has the cost model).
+
+The Gram tile's cross product X Z^T at fp32 precision costs, on v5e,
+HIGHEST's six bf16 MXU passes over d padded to 128 lanes: six passes a
+Gram entry for any d up to 128. Where fewer passes do (``ops.takes_packed``:
+ceil((6d + 6) / 128) < 6 ceil(d / 128), 6d for a dot-product family, so
+d = 18 takes 1, d = 28 2 and d = 90 5; d = 128 keeps HIGHEST), ops.py hands
+the kernels ``cross="packed"`` operands instead: the three-way bf16 splits
+of X and Z laid side by side so that one bf16 contraction with fp32
+accumulation sums the same six products (all nine where they fit the same
+lanes), plus the split row norms against ones and Z's exact -2 scale. The
+contraction is then the squared distance itself, and the epilogue only
+clamps it. Each pick is counted as ``kernels.cross.<cross>``
+(``runtime.spans``).
 
 Grid (n/bn,): Z (M, d) and the (M, kp) panel are VMEM-resident across the
 whole sweep (M*(d+kp) <= ~4M floats for the paper's d_eff-sized center
@@ -38,10 +52,11 @@ and the (bn, M) Gram tile need (``common.vmem_params``). The reductions
 (``falkon_matvec``/``knm_t``) revisit one output block every step and
 accumulate; ``knm_matvec`` writes a private (bn, kp) block per step.
 
-Mixed precision (``bf16=True``): the Gram tile's dominant (bn, d) x (d, M)
-product loads its operands as bf16 and accumulates on the MXU in fp32
-(``preferred_element_type``); the row norms, distance epilogue, exp, and the
-second-stage contractions all stay fp32. See DESIGN.md §2 for the measured
+Mixed precision (``cross="bf16"``, ops.py's ``bf16=True``): the Gram
+tile's dominant (bn, d) x (d, M) product loads its operands as bf16 and
+accumulates on the MXU in fp32 (``preferred_element_type``); the row
+norms, distance epilogue, exp, and the second-stage contractions all stay
+fp32. See DESIGN.md §2 for the measured
 parity tolerances (kernel values ~1e-2 relative on unit-scale data).
 """
 from __future__ import annotations
@@ -57,25 +72,41 @@ from ..common import FP32, mxu_precision, vmem_params
 
 
 def _gram_tile(x: jax.Array, z: jax.Array, *, kind: str, inv_scale: float,
-               bf16: bool) -> jax.Array:
-    """k(X_tile, Z) in VMEM; x (bn, d) and z (M, d) are fp32.
+               cross: str) -> jax.Array:
+    """k(X_tile, Z) in VMEM for an x (bn, ·) tile against z (M, ·).
 
     ``kind`` names a registered kernel family; its elementwise epilogue runs
-    here on the VPU (the same function body as the jnp reference). With
-    ``bf16`` the MXU product takes bf16 operands (fp32 accumulation); the
-    norms and epilogue are always fp32 so the only precision loss is the
-    cross-term of the squared distance.
+    here on the VPU (the same function body as the jnp reference).
+
+    ``cross`` says how the operands hold the cross product (ops.py picks):
+    ``"packed"``: x and z are the bf16 operands of ``ops._pack``, whose one
+    contraction with fp32 accumulation sums (at least) the six bf16
+    products of HIGHEST, and for a distance family also the split row norms
+    and the exact -2 scale, so it gives the squared distance itself.
+    ``"fp32"`` / ``"bf16"``: x and z are fp32 with d padded to 128 lanes;
+    the cross product runs at HIGHEST (six bf16 passes), or as one pass
+    over bf16 casts; the norms and epilogue are always fp32 so the only
+    precision loss of ``"bf16"`` is the cross-term of the squared distance.
     """
     fam = get_family(kind)
+    if cross == "packed":
+        pre = _contract(x, z, None)  # (bn, M)
+        return fam.epilogue(pre if fam.dot_only else jnp.maximum(pre, 0.0), inv_scale)
+    bf16 = cross == "bf16"
+    x, z = x.astype(jnp.float32), z.astype(jnp.float32)
     xc, zc = (x.astype(jnp.bfloat16), z.astype(jnp.bfloat16)) if bf16 else (x, z)
-    prod = jax.lax.dot_general(xc, zc, (((1,), (1,)), ((), ())),
-                               precision=mxu_precision(bf16),
-                               preferred_element_type=jnp.float32)  # (bn, M)
+    prod = _contract(xc, zc, mxu_precision(bf16))  # (bn, M)
     if fam.dot_only:
         return fam.epilogue(prod, inv_scale)
     d2 = jnp.maximum(jnp.sum(x * x, -1)[:, None] + jnp.sum(z * z, -1)[None, :]
                      - 2.0 * prod, 0.0)
     return fam.epilogue(d2, inv_scale)
+
+
+def _contract(x: jax.Array, z: jax.Array, precision) -> jax.Array:
+    """X Z^T over the shared lane axis, fp32 MXU accumulation."""
+    return jax.lax.dot_general(x, z, (((1,), (1,)), ((), ())), precision=precision,
+                               preferred_element_type=jnp.float32)
 
 
 def _panel_t_g(g: jax.Array, t: jax.Array) -> jax.Array:
@@ -86,12 +117,10 @@ def _panel_t_g(g: jax.Array, t: jax.Array) -> jax.Array:
 
 
 def _step_tile(x_ref, z_ref, *, kind: str, inv_scale: float, bn: int,
-               n_valid: int, bf16: bool) -> jax.Array:
+               n_valid: int, cross: str) -> jax.Array:
     """This grid step's (bn, M) Gram tile, padded X rows zeroed."""
     i = pl.program_id(0)
-    x = x_ref[...].astype(jnp.float32)  # (bn, d)
-    z = z_ref[...].astype(jnp.float32)  # (M, d)
-    g = _gram_tile(x, z, kind=kind, inv_scale=inv_scale, bf16=bf16)
+    g = _gram_tile(x_ref[...], z_ref[...], kind=kind, inv_scale=inv_scale, cross=cross)
     rows = i * bn + jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0)
     return jnp.where(rows < n_valid, g, 0.0)  # padded X rows contribute nothing
 
@@ -121,14 +150,14 @@ def _vec_matvec_kernel(x_ref, z_ref, v_ref, o_ref, **tile):
 
 def _fused_sweep(kernel, x, z, rhs, rhs_spec: pl.BlockSpec, out_shape, *,
                  kind: str, inv_scale: float, bn: int, n_valid: int,
-                 interpret: bool, bf16: bool) -> jax.Array:
+                 interpret: bool, cross: str) -> jax.Array:
     """The accumulating (n/bn,) sweep of the matvec and K_nM^T kernels: X
     tiles stream, Z and the ``out_shape`` output block stay resident."""
     n, d = x.shape
     m = z.shape[0]
     return pl.pallas_call(
         partial(kernel, kind=kind, inv_scale=float(inv_scale), bn=bn,
-                n_valid=n_valid, bf16=bf16),
+                n_valid=n_valid, cross=cross),
         grid=(n // bn,),
         in_specs=[pl.BlockSpec((bn, d), lambda i: (i, 0)),
                   pl.BlockSpec((m, d), lambda i: (0, 0)),
@@ -142,17 +171,17 @@ def _fused_sweep(kernel, x, z, rhs, rhs_spec: pl.BlockSpec, out_shape, *,
 
 
 @partial(jax.jit, static_argnames=("kind", "bn", "n_valid", "interpret",
-                                   "inv_scale", "bf16"))
+                                   "inv_scale", "cross"))
 def falkon_matvec_pallas(x: jax.Array, z: jax.Array, v: jax.Array, inv_scale: float,
                          *, kind: str = "gaussian", bn: int = 512, n_valid: int,
-                         interpret: bool = True, bf16: bool = False) -> jax.Array:
+                         interpret: bool = True, cross: str = "fp32") -> jax.Array:
     """K_nM^T K_nM V for pre-padded x (n, d), z (M, d), V (M, kp) -> (M, kp);
     a (1, M) row v (one live column) -> the (1, M) row on the VPU path."""
     n, d = x.shape
     m = z.shape[0]
     assert n % bn == 0 and d % 128 == 0 and m % 128 == 0
     tile = dict(kind=kind, inv_scale=inv_scale, bn=bn, n_valid=n_valid,
-                interpret=interpret, bf16=bf16)
+                interpret=interpret, cross=cross)
     if v.shape == (1, m):
         return _fused_sweep(_vec_matvec_kernel, x, z, v,
                             pl.BlockSpec((1, m), lambda i: (0, 0)), (1, m), **tile)
@@ -175,12 +204,12 @@ def _masked_matvec_kernel(x_ref, z_ref, v_ref, m_ref, o_ref, **tile):
 
 
 @partial(jax.jit, static_argnames=("kind", "bn", "n_valid", "interpret",
-                                   "inv_scale", "bf16"))
+                                   "inv_scale", "cross"))
 def falkon_matvec_masked_pallas(x: jax.Array, z: jax.Array, v: jax.Array,
                                 mask: jax.Array, inv_scale: float, *,
                                 kind: str = "gaussian", bn: int = 512,
                                 n_valid: int, interpret: bool = True,
-                                bf16: bool = False) -> jax.Array:
+                                cross: str = "fp32") -> jax.Array:
     """K_nM^T diag(m_j) K_nM V per column, pre-padded; mask (n, kp)."""
     n, d = x.shape
     m, kp = z.shape[0], v.shape[1]
@@ -188,7 +217,7 @@ def falkon_matvec_masked_pallas(x: jax.Array, z: jax.Array, v: jax.Array,
     assert mask.shape == (n, kp)
     return pl.pallas_call(
         partial(_masked_matvec_kernel, kind=kind, inv_scale=float(inv_scale),
-                bn=bn, n_valid=n_valid, bf16=bf16),
+                bn=bn, n_valid=n_valid, cross=cross),
         grid=(n // bn,),
         in_specs=[
             pl.BlockSpec((bn, d), lambda i: (i, 0)),
@@ -221,17 +250,17 @@ def _vec_knm_t_kernel(x_ref, z_ref, y_ref, o_ref, **tile):
 
 
 @partial(jax.jit, static_argnames=("kind", "bn", "n_valid", "interpret",
-                                   "inv_scale", "bf16"))
+                                   "inv_scale", "cross"))
 def knm_t_pallas(x: jax.Array, z: jax.Array, y: jax.Array, inv_scale: float,
                  *, kind: str = "gaussian", bn: int = 512, n_valid: int,
-                 interpret: bool = True, bf16: bool = False) -> jax.Array:
+                 interpret: bool = True, cross: str = "fp32") -> jax.Array:
     """K_nM^T Y for pre-padded x (n, d), z (M, d), Y (n, kp) -> (M, kp);
     a (1, n) row y (one live column) -> the (1, M) row on the VPU path."""
     n, d = x.shape
     m = z.shape[0]
     assert n % bn == 0 and d % 128 == 0 and m % 128 == 0
     tile = dict(kind=kind, inv_scale=inv_scale, bn=bn, n_valid=n_valid,
-                interpret=interpret, bf16=bf16)
+                interpret=interpret, cross=cross)
     if y.shape == (1, n):
         return _fused_sweep(_vec_knm_t_kernel, x, z, y,
                             pl.BlockSpec((1, bn), lambda i: (0, i)), (1, m), **tile)
@@ -242,29 +271,27 @@ def knm_t_pallas(x: jax.Array, z: jax.Array, y: jax.Array, inv_scale: float,
 
 
 def _knm_matvec_kernel(x_ref, z_ref, a_ref, o_ref, *, kind: str,
-                       inv_scale: float, bf16: bool):
+                       inv_scale: float, cross: str):
     """O_tile = k(X_tile, Z) A — the predict / KRR forward contraction.
 
     No cross-step accumulation: each grid step owns its (bn, kp) output
     block, so no init/revisit protocol is needed. Padded X rows produce
     garbage that ops.py slices off; padded Z rows meet A's zero padding.
     """
-    x = x_ref[...].astype(jnp.float32)  # (bn, d)
-    z = z_ref[...].astype(jnp.float32)  # (M, d)
-    g = _gram_tile(x, z, kind=kind, inv_scale=inv_scale, bf16=bf16)
+    g = _gram_tile(x_ref[...], z_ref[...], kind=kind, inv_scale=inv_scale, cross=cross)
     o_ref[...] = jnp.dot(g, a_ref[...].astype(jnp.float32), precision=FP32)  # (bn, kp)
 
 
-@partial(jax.jit, static_argnames=("kind", "bn", "interpret", "inv_scale", "bf16"))
+@partial(jax.jit, static_argnames=("kind", "bn", "interpret", "inv_scale", "cross"))
 def knm_matvec_pallas(x: jax.Array, z: jax.Array, alpha: jax.Array, inv_scale: float,
                       *, kind: str = "gaussian", bn: int = 512,
-                      interpret: bool = True, bf16: bool = False) -> jax.Array:
+                      interpret: bool = True, cross: str = "fp32") -> jax.Array:
     """K_nM A for pre-padded x (n, d), z (M, d), A (M, kp)."""
     n, d = x.shape
     m, kp = z.shape[0], alpha.shape[1]
     assert n % bn == 0 and d % 128 == 0 and m % 128 == 0 and kp % 128 == 0
     return pl.pallas_call(
-        partial(_knm_matvec_kernel, kind=kind, inv_scale=float(inv_scale), bf16=bf16),
+        partial(_knm_matvec_kernel, kind=kind, inv_scale=float(inv_scale), cross=cross),
         grid=(n // bn,),
         in_specs=[
             pl.BlockSpec((bn, d), lambda i: (i, 0)),

@@ -21,7 +21,10 @@ the trace, the compile or cache load and the dispatch, not the loop's run.
 between two by the shapes it is given (at trace time, like a retrace, so a
 loop traced once counts once); ``taken(name)`` reads it. The fused K_nM
 operators count ``kernels.falkon_matvec.{vector,panel}`` and
-``kernels.knm_t.{vector,panel}``: one live column or a multi-column panel.
+``kernels.knm_t.{vector,panel}``: one live column or a multi-column panel;
+and ``kernels.cross.{packed,fp32,bf16}``: the Gram tile's cross product as
+one bf16 contraction over packed splits (where d makes that fewer MXU
+passes), at HIGHEST over d padded to 128, or in bf16.
 ``count(name, amount)`` adds to a counter that ``counted(name)`` reads: the
 streamed passes count ``stream.chunks`` (chunk steps a pass runs) and
 ``stream.h2d_bytes`` (bytes a host source put on the device).

@@ -146,6 +146,25 @@ def test_knm_operators_parity(name):
                                atol=1e-4 * float(jnp.abs(g.T @ y).max()))
 
 
+@pytest.mark.parametrize("masked", [False, True])
+def test_pallas_knm_operators_pack_x_once(masked):
+    """The Pallas operator and its right-hand side share one packing of X and
+    Z (one ``kernels.cross.packed`` pick, however often the operator is
+    called), and give what ``knm_quadratic`` and ``knm_t`` give apart."""
+    from repro.runtime import spans
+
+    x, y, z = _problem()
+    v = jax.random.normal(jax.random.PRNGKey(3), (z.shape[0],))
+    mask = (y > 0) if masked else None
+    be = PallasBackend(interpret=True)
+    before = spans.taken("kernels.cross.packed")
+    quad, kty = be.knm_operators(KERN, x, z, y, mask=mask)
+    got = [quad(v), quad(2.0 * v)]
+    assert spans.taken("kernels.cross.packed") - before == 1
+    np.testing.assert_array_equal(got[0], be.knm_quadratic(KERN, x, z, mask=mask)(v))
+    np.testing.assert_array_equal(kty, be.knm_t(KERN, x, z, y, mask=mask))
+
+
 @pytest.mark.parametrize("name", BACKENDS)
 @pytest.mark.parametrize("n", [256, 300])  # tile-aligned and ragged (n % block != 0)
 def test_knm_matvec_parity(name, n):

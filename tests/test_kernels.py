@@ -155,6 +155,144 @@ def test_knm_t_kernel_shapes(n, m, d):
     np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4 * float(jnp.abs(ref).max()))
 
 
+@pytest.mark.parametrize("kind", ["linear", "gaussian"])
+@pytest.mark.parametrize("d", [1, 18, 21, 22, 28, 90, 128, 130])
+def test_packed_cross_product_is_as_exact_as_highest(d, kind):
+    """One bf16 contraction of the packed operands against a float64 oracle:
+    x.z for a dot-product family, the squared distance for a distance family
+    (norms folded in). Its largest error, relative to |x||z| (to ||x||^2 +
+    ||z||^2 for the distance), is at most 1.25x that of the fp32 HIGHEST
+    product (and the kernel's fp32 distance assembly) on the same data."""
+    from repro.kernels.falkon_matvec.falkon_matvec import _contract
+    from repro.kernels.falkon_matvec.ops import _pack
+
+    x = jax.random.normal(jax.random.PRNGKey(d), (2048, d))
+    z = jax.random.normal(jax.random.PRNGKey(d + 1), (512, d))
+    x64, z64 = np.asarray(x, np.float64), np.asarray(z, np.float64)
+    xz = x64 @ z64.T
+    packed = _contract(_pack(x, 2048, 0, kind), _pack(z, 512, 1, kind), None)
+    highest = _contract(x, z, jax.lax.Precision.HIGHEST)
+    if kind == "linear":
+        ref = xz
+        scale = np.linalg.norm(x64, axis=1)[:, None] * np.linalg.norm(z64, axis=1)[None]
+    else:
+        nx, nz = (x64 ** 2).sum(1)[:, None], (z64 ** 2).sum(1)[None]
+        ref, scale = nx + nz - 2 * xz, nx + nz
+        highest = (jnp.sum(x * x, -1)[:, None] + jnp.sum(z * z, -1)[None, :]
+                   - 2.0 * highest)
+    err = [np.max(np.abs(np.asarray(a, np.float64) - ref) / scale) for a in (packed, highest)]
+    assert err[0] <= 1.25 * err[1], err
+
+
+def _packed_case(op: str, x, z, v, w, mask, **kw):
+    """(kernel output, oracle) of one K_nM operator form on the same data."""
+    from repro.families import get_family
+    from repro.kernels.falkon_matvec import ops, ref
+
+    inv = float(get_family(kw["kind"]).inv_scale(1.5))
+    panel = jnp.stack([v, w], 1)
+    if op == "falkon_matvec":
+        return ops.falkon_matvec(x, z, v, 1.5, **kw), ref.falkon_matvec_ref(x, z, v, inv)
+    if op == "falkon_matvec_panel":
+        return (ops.falkon_matvec(x, z, panel, 1.5, **kw),
+                ref.falkon_matvec_ref(x, z, panel, inv))
+    if op == "falkon_matvec_masked":
+        masks = jnp.stack([mask, 1.0 - mask], 1)
+        return (ops.falkon_matvec(x, z, panel, 1.5, mask=masks, **kw),
+                ref.falkon_matvec_masked_ref(x, z, panel, masks, inv))
+    y = jnp.sin(x[:, 0] * 3.0)
+    if op == "knm_t":
+        return ops.knm_t(x, z, y, 1.5, **kw), ref.knm_t_ref(x, z, y, inv)
+    return ops.knm_matvec(x, z, v, 1.5, **kw), ref.knm_matvec_ref(x, z, v, inv)
+
+
+@pytest.mark.parametrize("d", [18, 28, 90])
+@pytest.mark.parametrize("op", ["falkon_matvec", "falkon_matvec_panel", "falkon_matvec_masked",
+                                "knm_t", "knm_matvec"])
+def test_packed_operators_match_reference(op, d):
+    """Every K_nM operator form on the packed path (d = 18, 28, 90: the
+    cells' widths) agrees with its fp32 oracle at the fp32 tolerances."""
+    n, m = 600, 200
+    x = jax.random.normal(jax.random.PRNGKey(0), (n, d)) / np.sqrt(d)
+    z = jax.random.normal(jax.random.PRNGKey(1), (m, d)) / np.sqrt(d)
+    v = jax.random.normal(jax.random.PRNGKey(2), (m,))
+    w = jax.random.normal(jax.random.PRNGKey(3), (m,))
+    mask = (jax.random.uniform(jax.random.PRNGKey(4), (n,)) < 0.7).astype(jnp.float32)
+    out, ref = _packed_case(op, x, z, v, w, mask, kind="gaussian", interpret=True, bn=256)
+    assert out.shape == ref.shape
+    np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4 * float(jnp.abs(ref).max()))
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("path", ["packed", "bf16"])
+def test_packed_fit_moves_predictions_no_more_than_rounding(path, seed, monkeypatch):
+    """At HIGGS's conditioning (d = 28, sigma = 22, lam = 1e-8, 20 CG
+    iterations), where a change of rounding alone moves held-out
+    predictions by ~1e-3, a fit through the packed operator lies no further
+    from the fp32 HIGHEST fit than twice what a rounding-only change moves
+    it (fp32 at another tile height; the jnp operator); a fit with the
+    lossy bf16 cast lies far outside. The preconditioner is the same in
+    every fit: host LAPACK eigh, as on a TPU."""
+    from repro.core import JnpBackend, PallasBackend, falkon_fit, make_kernel
+    from repro.core import falkon as falkon_mod
+    from repro.kernels.falkon_matvec import ops
+
+    def host_eigh(a):
+        shapes = (jax.ShapeDtypeStruct(a.shape[:1], a.dtype),
+                  jax.ShapeDtypeStruct(a.shape, a.dtype))
+        return jax.pure_callback(falkon_mod._host_eigh, shapes, a)
+
+    jax.clear_caches()  # no preconditioner traced with another eigh
+    monkeypatch.setattr(falkon_mod, "_eigh", host_eigh)
+    n, m = 4096, 256
+    k = jax.random.split(jax.random.PRNGKey(seed), 2)
+    x = jax.random.normal(k[0], (n + 2048, 28))
+    y = jnp.sign(jnp.sin(2 * x[:, 0]) + x[:, 1] * x[:, 2]
+                 + 0.3 * jax.random.normal(k[1], (n + 2048,)))
+    xte, x, y, z = x[n:], x[:n], y[:n], x[:m]
+    kern = make_kernel("gaussian", sigma=22.0)
+
+    def predict(be):
+        model = falkon_fit(kern, x, y, z, 1e-8, iters=20, backend=be)
+        return np.asarray(JnpBackend().knm_matvec(kern, xte, z, model.alpha), np.float64)
+
+    def gap(a, b):
+        return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+    try:
+        got = predict(PallasBackend(interpret=True, bn=256, bf16=path == "bf16"))
+        jnp_fit = predict(JnpBackend())
+        monkeypatch.setattr(ops, "takes_packed", lambda *a: False)
+        fp32 = predict(PallasBackend(interpret=True, bn=256))
+        rounding = max(gap(predict(PallasBackend(interpret=True, bn=512)), fp32),
+                       gap(jnp_fit, fp32))
+    finally:
+        jax.clear_caches()  # leave no program traced with the host eigh
+    assert (gap(got, fp32) <= 2.0 * rounding) == (path == "packed"), (gap(got, fp32), rounding)
+
+
+@pytest.mark.parametrize("d,bf16,path", [(18, False, "packed"), (28, False, "packed"),
+                                         (90, False, "packed"), (128, False, "fp32"),
+                                         (440, False, "packed"), (1536, False, "fp32"),
+                                         (18, True, "bf16")])
+def test_cross_product_path_follows_d(d, bf16, path):
+    """The cross product takes the packed operands where they need fewer MXU
+    passes than HIGHEST (d = 18, 28, 90, and TIMIT's 440: 21 passes against
+    24), not at d = 128 or at ImageNet's 1536 features (73 against 72), nor
+    with bf16; each pick is counted as ``kernels.cross.<path>``."""
+    from repro.kernels.falkon_matvec import ops
+    from repro.runtime import spans
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (300, d)) / np.sqrt(d)
+    z = x[:40]
+    names = [f"kernels.cross.{p}" for p in ("packed", "fp32", "bf16")]
+    before = {k: spans.taken(k) for k in names}
+    ops.falkon_matvec(x, z, jnp.ones((40,)), 1.5, interpret=True, bn=256, bf16=bf16)
+    got = {k: spans.taken(k) - before[k] for k in names}
+    assert got == {k: int(k.endswith(path)) for k in names}
+    assert ops.takes_packed(d, "gaussian", bf16) == (path == "packed")
+
+
 @pytest.mark.parametrize("s,chunk,h,p,n", [(96, 32, 4, 8, 16), (80, 32, 2, 16, 8),
                                            (128, 128, 8, 8, 16)])
 def test_ssd_kernel_shapes(s, chunk, h, p, n):

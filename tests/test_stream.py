@@ -512,13 +512,25 @@ def _pass_jaxprs(jaxpr):
                     yield from _pass_jaxprs(inner)
 
 
-@pytest.mark.parametrize("inner", ["pallas", "jnp"])
-def test_every_chunk_step_dot_requests_highest(inner):
-    """A DEFAULT float32 dot runs as one bf16 pass on a TPU: every dot of a
-    streamed pass's chunk step, for each operator, asks for HIGHEST."""
-    be = StreamBackend(inner=PALLAS if inner == "pallas" else JNP, chunk=64)
+#: The streamed operators whose Pallas chunk step runs the K_nM kernels,
+#: which take the packed cross product (the scorers keep their HIGHEST
+#: gram and quadform kernels).
+_PACKED_OPS = ("knm_quadratic", "knm_quadratic_masked", "knm_t", "knm_matvec")
+
+
+def _chunk_step_dot_faults(be: StreamBackend, d: int) -> list:
+    """What is wrong with the dots of ``be``'s streamed chunk steps, for
+    each operator at width ``d``: a float32 dot that does not ask for
+    HIGHEST (a DEFAULT float32 dot runs as one bf16 pass on a TPU); a bf16
+    dot that does not accumulate in float32 or does not contract over the
+    packed operands' lanes, round_up(6d + 6, 128); a K_nM chunk step of a
+    Pallas inner that does not count the packed pick, or that counts the
+    lossy ``kernels.cross.bf16``."""
+    from repro.kernels.common import round_up
+    from repro.kernels.falkon_matvec.ops import _packed_width
+
     n, m = 3 * 64 + 9, 16
-    x, y = _xy(n, d=4, seed=41)
+    x, y = _xy(n, d=d, seed=41)
     x, y = jnp.asarray(x), jnp.asarray(y)
     z, v = x[:m], jnp.linspace(-1.0, 1.0, m)
     mask, reg = jnp.ones((m,), bool), jnp.full((m,), 2.0)
@@ -530,14 +542,56 @@ def test_every_chunk_step_dot_requests_highest(inner):
         "masked_quadform": lambda: be.masked_quadform(KERN, x, z, mask, reg),
         "rls_scores": lambda: be.rls_scores(KERN, x, z, mask, reg, jnp.float32(2.0)),
     }
+    kp = round_up(_packed_width(d, KERN.name), 128)
+    picks = [f"kernels.cross.{c}" for c in ("packed", "bf16")]
+    faults = []
     for name, call in calls.items():
+        jax.clear_caches()  # trace anew, so the picks are counted
+        before = [spans.taken(p) for p in picks]
         (body,) = _pass_jaxprs(jax.make_jaxpr(call)().jaxpr)
+        packed, lossy = (spans.taken(p) - b for p, b in zip(picks, before))
         dots = list(_dots(body))
-        assert dots, name
+        if not dots:
+            faults.append((name, "no dot"))
         for eqn in dots:
+            if all(v.aval.dtype == jnp.bfloat16 for v in eqn.invars):
+                (lhs_contract, _), _ = eqn.params["dimension_numbers"]
+                lanes = eqn.invars[0].aval.shape[lhs_contract[0]]
+                if eqn.params["preferred_element_type"] != jnp.float32 or lanes != kp:
+                    faults.append((name, f"bf16 dot over {lanes} lanes"))
+                continue
             prec = eqn.params["precision"]
             prec = prec if isinstance(prec, tuple) else (prec, prec)
-            assert prec == (jax.lax.Precision.HIGHEST,) * 2, (name, prec)
+            if prec != (jax.lax.Precision.HIGHEST,) * 2:
+                faults.append((name, prec))
+        if lossy:
+            faults.append((name, "kernels.cross.bf16"))
+        if isinstance(be.inner, PallasBackend) and name in _PACKED_OPS and not packed:
+            faults.append((name, "not packed"))
+    return faults
+
+
+@pytest.mark.parametrize("inner", ["pallas", "jnp"])
+def test_every_chunk_step_dot_requests_highest(inner):
+    """Every float32 dot of a streamed pass's chunk step, for each operator,
+    asks for HIGHEST. Under a Pallas inner the K_nM chunk steps take the
+    packed cross product (d = 22: 256 packed lanes against 128 for d
+    itself): its dot takes bf16 operands, whose products are exact, over
+    the packed lanes, and accumulates in float32."""
+    be = StreamBackend(inner=PALLAS if inner == "pallas" else JNP, chunk=64)
+    assert _chunk_step_dot_faults(be, d=22) == []
+
+
+def test_chunk_step_dot_check_flags_a_bf16_cast():
+    """The check above tells the packed product from a lossy bf16 cast of X
+    and Z (``bf16=True``): the cast's dot contracts over d padded to 128
+    lanes, not the packed 256, and its pick is counted as bf16."""
+    be = StreamBackend(inner=PallasBackend(interpret=True, bf16=True), chunk=64)
+    faults = _chunk_step_dot_faults(be, d=22)
+    for name in _PACKED_OPS:
+        assert (name, "bf16 dot over 128 lanes") in faults
+        assert (name, "kernels.cross.bf16") in faults
+        assert (name, "not packed") in faults
 
 
 @pytest.mark.parametrize("source", ["device", "store"])

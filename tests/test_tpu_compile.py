@@ -8,11 +8,14 @@ fused scorer) for one chip of a ``v5e:2x2`` topology, compiles it with the
 TPU compiler installed beside JAX, and asserts the Mosaic kernel is there.
 The K_nM operators compile on both of their paths: one live column (the
 VPU kernels, also at susy.fit's d = 18, M = 5632 and at M = 16384) and a
-two-column panel (the MXU kernels). The streamed passes compile at
-higgs.fit's shapes (n = 1.05e7 rows, d = 28, M = 5632, 65536-row chunks;
-the ladder's last level scores 327680 candidates) as one loop over the
-chunks, within one chip's HBM: the fused kernel appears once for the loop
-body and once for a ragged tail, never once per chunk.
+two-column panel (the MXU kernels). At d = 128 the cross product runs at
+HIGHEST; at susy.fit's d = 18 (128 packed lanes) and msd.fit's d = 90
+(M = 8192, 640 packed lanes) it takes the packed bf16 operands, on every
+K_nM kernel. The streamed passes compile at higgs.fit's shapes (n =
+1.05e7 rows, d = 28, M = 5632, 65536-row chunks; the ladder's last level
+scores 327680 candidates; the packed chunk is 65536 x 256 bf16) as one
+loop over the chunks, within one chip's HBM: the fused kernel appears
+once for the loop body and once for a ragged tail, never once per chunk.
 Nothing runs, so no chip is needed; where the topology cannot be described
 the fixture skips.
 
@@ -26,11 +29,13 @@ import jax.numpy as jnp
 
 from repro.core import PallasBackend, make_kernel
 from repro.kernels.quadform import ops as quadform_ops
+from repro.runtime import spans
 
 N, D, M = 1 << 20, 128, 8192
 M_FUSED = 1024  # the fused scorer's largest center buffer (rls_score.ops.MAX_FUSED_M)
 N_SCORE = 1 << 17  # candidate rows of one composed ladder level: (N_SCORE, M) fits HBM
 D_SUSY, M_SUSY, M_WIDE = 18, 5632, 16384  # susy.fit's width and centers; the widest M
+N_MSD, D_MSD, M_MSD = 463_715, 90, 8192  # msd.fit's rows, width and centers
 N_HIGGS, D_HIGGS, CHUNK = 10_500_000, 28, 65536  # higgs.fit: 160 chunks and a 14240-row tail
 R_LADDER = 327_680  # higgs.fit's last ladder level: 3e5 candidates in a quarter-pow2 buffer
 
@@ -92,6 +97,17 @@ def _cases(s):
         "knm_t_wide": (lambda x, z, y: be.knm_t(kern, x, z, y),
                        s(N, D_SUSY), s(M_WIDE, D_SUSY), s(N)),
         "knm_matvec": (lambda x, z, a: be.knm_matvec(kern, x, z, a), s(N, D), s(M, D), s(M)),
+        "falkon_matvec_msd": (lambda x, z, v: be.knm_quadratic(kern, x, z)(v),
+                              s(N_MSD, D_MSD), s(M_MSD, D_MSD), s(M_MSD)),
+        "knm_t_msd": (lambda x, z, y: be.knm_t(kern, x, z, y),
+                      s(N_MSD, D_MSD), s(M_MSD, D_MSD), s(N_MSD)),
+        "falkon_matvec_masked_susy": (
+            lambda x, z, v, m: be.knm_quadratic(kern, x, z, mask=m)(v),
+            s(N, D_SUSY), s(M_SUSY, D_SUSY), s(M_SUSY), s(N)),
+        "falkon_matvec_panel_susy": (lambda x, z, v: be.knm_quadratic(kern, x, z)(v),
+                                     s(N, D_SUSY), s(M_SUSY, D_SUSY), s(M_SUSY, 2)),
+        "knm_matvec_susy": (lambda x, z, a: be.knm_matvec(kern, x, z, a),
+                            s(N, D_SUSY), s(M_SUSY, D_SUSY), s(M_SUSY)),
     }
 
 
@@ -99,11 +115,16 @@ def _cases(s):
                                   "falkon_matvec_masked", "knm_t", "knm_matvec",
                                   "falkon_matvec_panel", "falkon_matvec_susy",
                                   "falkon_matvec_wide", "knm_t_panel", "knm_t_susy",
-                                  "knm_t_wide"])
+                                  "knm_t_wide", "falkon_matvec_msd", "knm_t_msd",
+                                  "falkon_matvec_masked_susy", "falkon_matvec_panel_susy",
+                                  "knm_matvec_susy"])
 def test_kernel_compiles_for_v5e(shapes, name):
     fn, *args = _cases(shapes)[name]
+    before = spans.taken("kernels.cross.packed")
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    if name.startswith(("falkon_matvec", "knm_")):  # the K_nM kernels pick by d
+        assert (spans.taken("kernels.cross.packed") > before) == (args[0].shape[1] < D)
 
 
 @pytest.mark.parametrize("op", ["knm_quadratic", "knm_t", "rls_scores"])
@@ -129,6 +150,10 @@ def test_streamed_pass_compiles_for_v5e_without_unrolling(shapes, op):
             x, None, (z, maskf, inv_l, jnp.float32(1e4)), jnp.zeros((n,)),
             step=_rls_chunk, kernel=_static_kernel(kern), inner=be.inner, chunk=CHUNK,
             rows=True)
+    before = spans.taken("kernels.cross.packed")
     text = jax.jit(fn).lower(shapes(n, D_HIGGS), z, *rest).compile().as_text()
     calls = 1 + (n % CHUNK > 0)  # the loop body's kernel and the tail's
     assert text.count('custom_call_target="tpu_custom_call"') == calls
+    # the operator's chunks take the packed cross product; the scorer's
+    # chunks build their tile with the gram kernel, which keeps HIGHEST
+    assert (spans.taken("kernels.cross.packed") > before) == (op != "rls_scores")
